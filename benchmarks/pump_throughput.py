@@ -6,8 +6,7 @@ round, a pass over the dataset takes ~W times fewer dispatched rounds —
 and with them ~W times fewer host polls at a fixed ``poll_every`` —
 while the answers stay at single-stream recall. This benchmark serves
 the same query batch through `MatchServer(mesh=..., pump=True)` at
-worker counts 1 / 2 / 8 (forced host devices, spawned in a subprocess
-so it runs anywhere) plus the plain single-stream server, and measures:
+worker counts 1 / 2 / 8 plus the plain single-stream server, and measures:
 
   * tuples ingested/sec — wall-clock ingest bandwidth of the batch
     (on real accelerator pods this scales with aggregate worker I/O;
@@ -18,6 +17,11 @@ so it runs anywhere) plus the plain single-stream server, and measures:
     rounds, so both drop ~Wx
   * recall — against planted ground truth, must match the single
     stream at every width
+
+The workers are 8 virtual CPU devices (``JAX_PLATFORMS=cpu`` and
+``--xla_force_host_platform_device_count=8``, set by the child process
+this suite spawns, on any machine, accelerator or not), so its wall
+times are XLA:CPU times, and the report's ``config`` says so.
 
 Embedded golden check: the 1-worker pump IS the single stream (same
 visit order, same windows), so its trajectory must reproduce the plain
@@ -95,9 +99,10 @@ def _recall(ds, targets, results) -> float:
 
 
 def measure_phase() -> None:
-    """Entry point executed with 8 forced host devices: serve the batch
+    """Entry point executed on 8 virtual CPU devices: serve the batch
     through the plain server and through the pump at each worker count,
-    print one JSON line consumed by `run` in the parent."""
+    print one JSON line (with the child's device stamp) consumed by
+    `run` in the parent."""
     import jax
     from jax.sharding import Mesh
 
@@ -130,11 +135,13 @@ def measure_phase() -> None:
     for w in WORKERS:
         mesh = Mesh(np.array(jax.devices()[:w]).reshape(w, 1), ("data", "model"))
         out[f"w{w}"] = serve(mesh=mesh, pump=True, prefetch=not SMOKE)
+    out["env"] = env_stamp()
     print(json.dumps(out))
 
 
 def run(rows: list) -> None:
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = (
         str(pathlib.Path(__file__).parent.parent / "src")
@@ -149,6 +156,7 @@ def run(rows: list) -> None:
     if out.returncode != 0:
         raise SystemExit(f"pump measure phase failed:\n{out.stderr[-4000:]}")
     m = json.loads(out.stdout.strip().splitlines()[-1])
+    child_env = m.pop("env")
 
     single, w1, w8 = m["single"], m["w1"], m["w8"]
     sync_reduction = w1["loop_syncs"] / max(w8["loop_syncs"], 1)
@@ -177,7 +185,8 @@ def run(rows: list) -> None:
     report = dict(
         config=dict(
             workers=list(WORKERS), n_queries=N_QUERIES, lookahead=LOOKAHEAD,
-            k=K, eps=EPS, delta=DELTA, smoke=SMOKE, **env_stamp(),
+            k=K, eps=EPS, delta=DELTA, smoke=SMOKE, **child_env,
+            devices="8 virtual CPU devices (JAX_PLATFORMS=cpu)",
         ),
         serve=m,
         sync_reduction_w8=round(sync_reduction, 3),

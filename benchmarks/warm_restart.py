@@ -13,6 +13,10 @@ restart analogue of the serve benchmark's I/O amortization:
   3. A cold server (fresh cache, same configuration) serves the same
      fresh batch.
 
+Steps 1 and 3 run in one child process and step 2 in a second, one
+after the other; the parent only orchestrates and never initialises a
+JAX backend, so on an accelerator each child in turn holds the device.
+
 Acceptance: the warm-restored server must read STRICTLY fewer tuples
 per query than the cold server at no recall loss against planted
 ground truth.
@@ -108,10 +112,40 @@ def _recall(ds, targets, results) -> float:
     ]))
 
 
+def day1_phase() -> None:
+    """Child process 1: serve the warmup batch, checkpoint the cache to
+    $RESTART_BENCH_CKPT, then serve the fresh batch on a cold server.
+    Prints one JSON line consumed by `run` in the parent."""
+    ds, blocked = _build()
+    day1 = MatchServer(
+        blocked, max_queries=MAX_QUERIES, lookahead=LOOKAHEAD, seed=200, k_cap=K,
+        checkpoint_dir=os.environ["RESTART_BENCH_CKPT"],
+    )
+    _serve(day1, _warmup_targets(ds))
+    t0 = time.perf_counter()
+    day1.save_cache()
+    save_s = time.perf_counter() - t0
+
+    cold_server = MatchServer(
+        blocked, max_queries=MAX_QUERIES, lookahead=LOOKAHEAD, seed=200, k_cap=K
+    )
+    fresh = _fresh_targets(ds)
+    t0 = time.perf_counter()
+    cold_results = _serve(cold_server, fresh)
+    print(json.dumps(dict(
+        save_s=save_s,
+        serve_s=time.perf_counter() - t0,
+        tuples=[int(r.tuples_read) for r in cold_results],
+        total_tuples=int(cold_server.metrics["total_tuples_read"]),
+        recall=_recall(ds, fresh, cold_results),
+        env=env_stamp(),
+    )))
+
+
 def restore_phase() -> None:
-    """Entry point executed in a NEW process: warm-restore the server
-    from $RESTART_BENCH_CKPT and serve the fresh batch. Prints one JSON
-    line consumed by `run` in the parent."""
+    """Child process 2: warm-restore the server from $RESTART_BENCH_CKPT
+    and serve the fresh batch. Prints one JSON line consumed by `run`
+    in the parent."""
     ckpt = os.environ["RESTART_BENCH_CKPT"]
     ds, blocked = _build()
     t0 = time.perf_counter()
@@ -135,52 +169,34 @@ def restore_phase() -> None:
     )))
 
 
-def run(rows: list) -> None:
-    ds, blocked = _build()
-    ckpt = tempfile.mkdtemp(prefix="fastmatch_restart_bench_")
-
-    # -- day 1: warm the cache, checkpoint it ---------------------------
-    day1 = MatchServer(
-        blocked, max_queries=MAX_QUERIES, lookahead=LOOKAHEAD, seed=200, k_cap=K,
-        checkpoint_dir=ckpt,
-    )
-    _serve(day1, _warmup_targets(ds))
-    t0 = time.perf_counter()
-    day1.save_cache()
-    save_s = time.perf_counter() - t0
-
-    # -- warm restart: restore + serve in a NEW process -----------------
+def _child(phase: str, ckpt: str) -> dict:
+    """Run one phase in a fresh interpreter; its last stdout line."""
+    root = pathlib.Path(__file__).parent.parent
     env = dict(os.environ)
     env["RESTART_BENCH_CKPT"] = ckpt
-    env["PYTHONPATH"] = (
-        str(pathlib.Path(__file__).parent.parent / "src")
-        + os.pathsep + env.get("PYTHONPATH", "")
-    )
+    env["PYTHONPATH"] = str(root / "src") + os.pathsep + env.get("PYTHONPATH", "")
     out = subprocess.run(
         [sys.executable, "-c",
-         "from benchmarks.warm_restart import restore_phase; restore_phase()"],
-        env=env, capture_output=True, text=True, timeout=1800,
-        cwd=str(pathlib.Path(__file__).parent.parent),
+         f"from benchmarks.warm_restart import {phase}; {phase}()"],
+        env=env, capture_output=True, text=True, timeout=1800, cwd=str(root),
     )
     if out.returncode != 0:
-        raise SystemExit(f"restore phase failed:\n{out.stderr[-4000:]}")
-    warm = json.loads(out.stdout.strip().splitlines()[-1])
+        raise SystemExit(f"{phase} failed:\n{out.stderr[-4000:]}")
+    return json.loads(out.stdout.strip().splitlines()[-1])
 
-    # -- cold restart: same fresh workload, empty cache -----------------
-    cold_server = MatchServer(
-        blocked, max_queries=MAX_QUERIES, lookahead=LOOKAHEAD, seed=200, k_cap=K
-    )
-    fresh = _fresh_targets(ds)
-    t0 = time.perf_counter()
-    cold_results = _serve(cold_server, fresh)
-    cold_s = time.perf_counter() - t0
-    cold_tuples = [int(r.tuples_read) for r in cold_results]
-    cold_recall = _recall(ds, fresh, cold_results)
+
+def run(rows: list) -> None:
+    ckpt = tempfile.mkdtemp(prefix="fastmatch_restart_bench_")
+    # day 1 + cold restart, then the warm restore in a NEW process
+    cold = _child("day1_phase", ckpt)
+    warm = _child("restore_phase", ckpt)
+    save_s, cold_s = cold["save_s"], cold["serve_s"]
+    cold_tuples, cold_recall = cold["tuples"], cold["recall"]
 
     # totals are ACTUAL I/O (shared reads counted once), per-query
     # numbers in the report are the usual while-live amortized counters
     warm_total = warm["total_tuples"]
-    cold_total = int(cold_server.metrics["total_tuples_read"])
+    cold_total = cold["total_tuples"]
     amortization = cold_total / max(warm_total, 1)
     ok = warm_total < cold_total and warm["recall"] >= cold_recall
 
@@ -198,7 +214,7 @@ def run(rows: list) -> None:
         config=dict(
             v_z=SPEC.v_z, v_x=SPEC.v_x, num_tuples=SPEC.num_tuples,
             n_warmup=N_WARMUP, n_fresh=N_FRESH, lookahead=LOOKAHEAD,
-            k=K, eps=EPS, delta=DELTA, smoke=SMOKE, **env_stamp(),
+            k=K, eps=EPS, delta=DELTA, smoke=SMOKE, **cold["env"],
         ),
         cold=dict(tuples_per_query=cold_tuples, total_tuples=cold_total,
                   recall=cold_recall, serve_s=round(cold_s, 4)),

@@ -39,9 +39,12 @@ Communication per round: one psum of the (counts, row-sum) delta pair
 samples ingested AND of the number of query slots (the batched tau
 reads each shard's counts rows once, not Q times).
 Sample bytes never cross the network; this is what makes the engine
-scale to 1000+ nodes. `SharedCountsScheduler(mesh=...)` is the GSPMD
-(sharding-propagation) counterpart for serving; this explicit
-shard_map round is the collective-auditable data-parallel ingest path.
+scale to 1000+ nodes. `SharedCountsScheduler(mesh=...)` serves one
+global window stream through the same ingest and statistics pieces
+(`make_stream_rounds`); this round is the data-parallel ingest path.
+Every round here is an explicit shard_map program: a Pallas TPU kernel
+cannot be partitioned automatically, so each kernel call sits in a
+region that is manual over the whole mesh.
 
 The PUMP round (`make_pump_round`) is the self-feeding variant of the
 same collective structure, built for `repro.core.pump.DistributedPump`:
@@ -57,7 +60,8 @@ all-gather — window bytes never leave the worker that read them.
 
 from __future__ import annotations
 
-import inspect
+import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -67,7 +71,9 @@ from repro.core.multiquery import (
     CacheSnapshot,
     MultiQuerySpec,
     MultiQueryState,
+    RoundFns,
     SampleCursor,
+    _advance_cursor,
     apply_stats,
 )
 from repro.core.policies import mark_window
@@ -80,28 +86,12 @@ __all__ = [
     "make_distributed_round",
     "make_pump_ingest_round",
     "make_pump_round",
+    "make_stats_step",
+    "make_stream_rounds",
     "multi_state_pspecs",
     "place_cache",
-    "shard_map_compat",
     "window_pspecs",
 ]
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions (jax.shard_map / experimental;
-    check_vma / check_rep) with replication checking off — the round's
-    replicated outputs come out of collectives the checker can't see
-    through on every version we support."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    kwargs = {}
-    if "check_vma" in params:
-        kwargs["check_vma"] = False
-    elif "check_rep" in params:
-        kwargs["check_rep"] = False
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
 
 
 def multi_state_pspecs(model_axis: str = "model") -> MultiQueryState:
@@ -201,6 +191,15 @@ def place_cache(snap: CacheSnapshot, mesh, model_axis: str = "model") -> CacheSn
     return jax.tree.map(jax.device_put, host, shardings)
 
 
+def _manual(mesh, fn, in_specs, out_specs):
+    """Jit ``fn`` as one shard_map program, manual over the whole mesh
+    (replication checking off: the rounds' replicated outputs come out
+    of collectives)."""
+    return jax.jit(jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    ))
+
+
 def make_distributed_round(
     mesh,
     spec: MultiQuerySpec,
@@ -226,13 +225,7 @@ def make_distributed_round(
     shapes — each worker's kernels see (vz_shard, V_X), not the global
     V_Z, so that is the key the tuner must have measured.
     """
-    model_size = mesh.shape[model_axis]
-    if spec.v_z % model_size != 0:
-        raise ValueError(
-            f"V_Z={spec.v_z} must divide by model axis size {model_size} "
-            "(pad candidates to a multiple; padded rows are never sampled)"
-        )
-    vz_shard = spec.v_z // model_size
+    vz_shard = _check_vz(spec, mesh, model_axis)
     sample_axes = tuple(data_axes)
     if plans is None:
         plans = autotune.resolve_plans(
@@ -250,10 +243,7 @@ def make_distributed_round(
 
     specs = multi_state_pspecs(model_axis=model_axis)
     sample_spec = P(sample_axes)
-    shmapped = shard_map_compat(
-        round_fn, mesh, in_specs=(specs, sample_spec, sample_spec), out_specs=specs
-    )
-    return jax.jit(shmapped)
+    return _manual(mesh, round_fn, (specs, sample_spec, sample_spec), specs)
 
 
 def _shard_ingest(
@@ -426,10 +416,7 @@ def make_pump_round(
     specs = multi_state_pspecs(model_axis=model_axis)
     cspecs = cursor_pspecs(data_axes=sample_axes)
     wspecs = window_pspecs(data_axes=sample_axes)
-    shmapped = shard_map_compat(
-        round_fn, mesh, in_specs=(specs, cspecs, wspecs), out_specs=(specs, cspecs)
-    )
-    return jax.jit(shmapped)
+    return _manual(mesh, round_fn, (specs, cspecs, wspecs), (specs, cspecs))
 
 
 def make_pump_ingest_round(
@@ -472,7 +459,98 @@ def make_pump_ingest_round(
     specs = multi_state_pspecs(model_axis=model_axis)
     cspecs = cursor_pspecs(data_axes=sample_axes)
     wspecs = window_pspecs(data_axes=sample_axes)
-    shmapped = shard_map_compat(
-        round_fn, mesh, in_specs=(specs, cspecs, wspecs), out_specs=(specs, cspecs)
+    return _manual(mesh, round_fn, (specs, cspecs, wspecs), (specs, cspecs))
+
+
+def make_stats_step(
+    mesh, spec: MultiQuerySpec, *, model_axis: str = "model", plans=None
+):
+    """Build the jitted shard_map statistics step over a candidate-sharded
+    state — the mesh twin of `multiquery.stats_step` (state -> state).
+    ``plans`` follows the `make_distributed_round` contract (shard-shape
+    plan key)."""
+    vz_shard = _check_vz(spec, mesh, model_axis)
+    if plans is None:
+        plans = autotune.resolve_plans(
+            vz_shard, spec.v_x, spec.max_queries, metric=spec.metric
+        )
+    specs = multi_state_pspecs(model_axis=model_axis)
+    return _manual(
+        mesh, partial(_shard_stats, spec=spec, model_axis=model_axis, plan=plans.tau),
+        (specs,), specs,
     )
-    return jax.jit(shmapped)
+
+
+def make_stream_rounds(
+    mesh,
+    spec: MultiQuerySpec,
+    *,
+    model_axis: str = "model",
+    policy: str = "anyactive",
+    plans=None,
+) -> RoundFns:
+    """Build the rounds `SharedCountsScheduler(mesh=...)` dispatches: ONE
+    global window stream over a candidate-sharded counts matrix.
+
+    The window, the sampling cursor and the per-query statistics are
+    replicated; every device marks the whole window (the same marks
+    everywhere), takes its 1/D share of the window's samples along the
+    mesh's other (data) axes, and histograms that share restricted to
+    its model shard's candidate rows; one psum over the data axes merges
+    the shares. The statistics tail is the pump's (`_shard_stats`), and
+    an all-empty round leaves the statistics untouched through the same
+    branchless select as the pump round. Semantics are `fused_round` /
+    `ingest_round` / `stats_step` on the sharded state, as explicit
+    shard_map programs (see the module docstring for why). V_Z must
+    divide by the model axis, as for every candidate-sharded round.
+    ``plans`` follows the `make_distributed_round` contract (shard-shape
+    plan key).
+    """
+    vz_shard = _check_vz(spec, mesh, model_axis)
+    data_axes = tuple(a for a in mesh.axis_names if a != model_axis)
+    num_data = math.prod(mesh.shape[a] for a in data_axes)
+    if plans is None:
+        plans = autotune.resolve_plans(
+            vz_shard, spec.v_x, spec.max_queries, metric=spec.metric
+        )
+
+    def ingest(state: MultiQueryState, wd: WindowData, marks: jax.Array):
+        zw = jnp.where(marks[:, None], wd.z, jnp.int32(-1)).reshape(-1)
+        xw = jnp.where(marks[:, None], wd.x, jnp.int32(-1)).reshape(-1)
+        if num_data > 1:  # this device's share of the window's samples
+            per = -(-zw.size // num_data)
+            lo = _worker_lo(mesh, data_axes, per)
+            zw, xw = (
+                jax.lax.dynamic_slice_in_dim(
+                    jnp.pad(a, (0, per * num_data - a.size), constant_values=-1), lo, per
+                )
+                for a in (zw, xw)
+            )
+        return _shard_ingest(
+            state, zw, xw, spec=spec, vz_shard=vz_shard, sample_axes=data_axes,
+            model_axis=model_axis, histogram_impl="auto", onehot_dtype=jnp.float32,
+            plan=plans.ingest,
+        )
+
+    def round_fn(state: MultiQueryState, cursor: SampleCursor, wd: WindowData):
+        marks = mark_window(wd.bitmap, state.union_words, policy=policy)
+        marks = marks & wd.valid & ~cursor.read_mask[wd.indices]
+        new_state = _shard_stats(
+            ingest(state, wd, marks), spec=spec, model_axis=model_axis, plan=plans.tau
+        )
+        n_marked = jnp.sum(marks.astype(jnp.int32))
+        state = jax.tree.map(
+            lambda new, old: jnp.where(n_marked > 0, new, old), new_state, state
+        )
+        return state, _advance_cursor(cursor, wd, marks)
+
+    def ingest_fn(state: MultiQueryState, cursor: SampleCursor, wd: WindowData):
+        marks = wd.valid & ~cursor.read_mask[wd.indices]
+        return ingest(state, wd, marks), _advance_cursor(cursor, wd, marks)
+
+    specs = multi_state_pspecs(model_axis=model_axis)
+    return RoundFns(
+        round=_manual(mesh, round_fn, (specs, P(), P()), (specs, P())),
+        ingest=_manual(mesh, ingest_fn, (specs, P(), P()), (specs, P())),
+        stats=make_stats_step(mesh, spec, model_axis=model_axis, plans=plans),
+    )

@@ -105,6 +105,7 @@ __all__ = [
     "QTYPE_TOPK",
     "QTYPE_CLOSENESS",
     "QueryOutcome",
+    "RoundFns",
     "SampleCursor",
     "SharedCountsScheduler",
     "StopPolicy",
@@ -678,6 +679,16 @@ def ingest_round(
     return state, _advance_cursor(cursor, wd, marks)
 
 
+class RoundFns(NamedTuple):
+    """The device programs one scheduler dispatches (jitted, static
+    arguments bound): the single-device rounds above, or a mesh's
+    shard_map rounds (`repro.core.distributed`)."""
+
+    round: Callable  # (state, cursor, wd) -> (state, cursor): mark + ingest + stats
+    ingest: Callable  # (state, cursor, wd) -> (state, cursor): exact completion
+    stats: Callable  # state -> state: one statistics step
+
+
 def slot_state(state: MultiQueryState, slot: int) -> HistSimState:
     """Single-query `HistSimState` view of one slot (counts/n are shared)."""
     return HistSimState(
@@ -917,9 +928,11 @@ class SharedCountsScheduler:
 
     With ``mesh`` given, the shared counts matrix is placed sharded
     ``P(model_axis, None)`` (samples-per-candidate ``P(model_axis)``)
-    and every jitted step runs SPMD across the mesh — the GSPMD
-    counterpart of the explicit-collective
-    `repro.core.distributed.make_distributed_round`.
+    and every round runs as one shard_map program across the mesh
+    (`repro.core.distributed.make_stream_rounds`): the single window
+    stream is replicated, its samples split over the other mesh axes,
+    and each device histograms its candidate rows. V_Z must divide by
+    the model axis size; otherwise construction raises ValueError.
     """
 
     def __init__(
@@ -977,6 +990,7 @@ class SharedCountsScheduler:
 
         self.state = init_multi_state(spec)
         self.cursor = self._place_cursor(init_cursor(nb))
+        self._rounds = self._make_rounds(mesh, model_axis, plans)
         if mesh is not None:
             from jax.sharding import NamedSharding
             from repro.core.distributed import multi_state_pspecs
@@ -1078,6 +1092,27 @@ class SharedCountsScheduler:
         `import_cache`, so a restored snapshot always lands with the
         same placement as a fresh cursor."""
         return cursor
+
+    def _make_rounds(self, mesh, model_axis: str, plans) -> RoundFns:
+        """The device programs this scheduler dispatches, chosen once.
+
+        Without a mesh: `fused_round` / `ingest_round` / `stats_step`
+        under ``self.plans``. With one: the explicit shard_map rounds of
+        `repro.core.distributed.make_stream_rounds`, their plans keyed on
+        the shard shapes unless ``plans`` (the caller's pair) pins them.
+        `repro.core.pump.DistributedPump` overrides this with its
+        per-worker rounds."""
+        if mesh is None:
+            return RoundFns(
+                round=partial(fused_round, spec=self.spec, policy=self.policy, plans=self.plans),
+                ingest=partial(ingest_round, spec=self.spec, plans=self.plans),
+                stats=partial(stats_step, spec=self.spec, plan=self.plans.tau),
+            )
+        from repro.core.distributed import make_stream_rounds
+
+        return make_stream_rounds(
+            mesh, self.spec, model_axis=model_axis, policy=self.policy, plans=plans
+        )
 
     def _global_read_mask(self) -> jax.Array:
         """The (num_blocks,) global read_mask view of the device cursor
@@ -1313,7 +1348,7 @@ class SharedCountsScheduler:
         Must run before any admission — importing under live tickets
         would invalidate their admission-time counter snapshots, so that
         is refused. Counts/n are placed with the scheduler's existing
-        sharding (the GSPMD mesh placement when constructed with
+        sharding (the candidate-sharded placement when constructed with
         ``mesh=``); cursor leaves are re-materialized host-side so their
         placement matches a freshly constructed scheduler's.
         """
@@ -1431,7 +1466,7 @@ class SharedCountsScheduler:
             ),
             gap=jnp.asarray(gap, jnp.float32),
         )
-        self.state = stats_step(self.state, spec=self.spec, plan=self.plans.tau)
+        self.state = self._rounds.stats(self.state)
         self._sync()  # fresh counters for the ticket + fresh delta_upper
         qid = self._next_qid
         self._next_qid += 1
@@ -1666,10 +1701,7 @@ class SharedCountsScheduler:
     def _dispatch_round(self, wd: WindowData) -> None:
         """One fused sampling round over prepared window data (no host
         sync — polling is the loop's cadence decision)."""
-        self.state, self.cursor = fused_round(
-            self.state, self.cursor, wd,
-            spec=self.spec, policy=self.policy, plans=self.plans,
-        )
+        self.state, self.cursor = self._rounds.round(self.state, self.cursor, wd)
 
     def _fetch_window(self, win: np.ndarray) -> WindowData:
         """Window data for one ad-hoc (global-id) window — the pump
@@ -1679,9 +1711,7 @@ class SharedCountsScheduler:
 
     def _dispatch_ingest(self, wd: WindowData) -> None:
         """One exact-completion ingest round over prepared window data."""
-        self.state, self.cursor = ingest_round(
-            self.state, self.cursor, wd, spec=self.spec, plans=self.plans
-        )
+        self.state, self.cursor = self._rounds.ingest(self.state, self.cursor, wd)
 
     def run_window(self, win: np.ndarray) -> int:
         """Mark one lookahead window against the union active set and
@@ -1747,7 +1777,7 @@ class SharedCountsScheduler:
                 windows += 1
         finally:
             stream.close()
-        self.state = stats_step(self.state, spec=self.spec, plan=self.plans.tau)
+        self.state = self._rounds.stats(self.state)
         self._sync()
         if self.telemetry is not None:
             self.telemetry.tracer.emit(

@@ -2,21 +2,22 @@
 explicit-collective distributed round.
 
 The paper's 35x speedup rests on many asynchronous block-based samplers
-feeding one statistics engine (FastMatch Sec 5). Until this module the
-mesh serving path was GSPMD-only: `SharedCountsScheduler(mesh=...)`
-shards the counts matrix, but every window is still gathered by ONE
-host stream and handed to the jitted round as replicated data — ingest
-bandwidth does not grow with the mesh. `DistributedPump` closes that
+feeding one statistics engine (FastMatch Sec 5). The single-stream
+mesh path, `SharedCountsScheduler(mesh=...)`, shards the counts matrix,
+but every window is still gathered by ONE host stream and handed to the
+round as replicated data — ingest bandwidth does not grow with the
+mesh. `DistributedPump` closes that
 gap: it is a `SharedCountsScheduler` whose sampling side is
 data-parallel end to end.
 
 How the two mesh paths dispatch (see also
 `repro.serve.fastmatch_server.MatchServer`):
 
-  GSPMD (``MatchServer(mesh=...)``)
-      One global block stream; `multiquery.fused_round` jitted over
-      state placed per `distributed.multi_state_pspecs`. XLA's sharding
-      propagation decides the collectives; window bytes are gathered
+  STREAM (``MatchServer(mesh=...)``)
+      One global block stream; the explicit shard_map round
+      `distributed.make_stream_rounds` over state placed per
+      `distributed.multi_state_pspecs`, the window replicated and its
+      samples split over the data axes; window bytes are gathered
       centrally.
 
   PUMP (``MatchServer(mesh=..., pump=True)``)
@@ -46,7 +47,7 @@ own id range (`distributed.cursor_pspecs`), which is what makes the
 without-replacement guarantee per-worker local — no read_mask traffic.
 
 Golden contract (tests/test_pump.py): driven with the same global
-windows, a pump round is bit-identical to the single-stream GSPMD
+windows, a pump round is bit-identical to the single-stream
 `fused_round` — counts, n, tau, bounds, read_mask and counters — for
 any mesh shape, mid-stream admission and retirement included. The
 host-side loop (pass structure, poll_every staleness, exact-completion
@@ -56,7 +57,7 @@ convert between the data-sharded padded read_mask and the global
 `CacheSnapshot` layout, so snapshots are interchangeable across pump
 widths and with the single-stream scheduler (elastic restart, e.g.
 checkpoint under 8 workers, restore under 4 — `cache_pspecs` re-places
-the candidate-sharded counts exactly as in the GSPMD path).
+the candidate-sharded counts exactly as in the single-stream path).
 
 `benchmarks/pump_throughput.py` measures the scaling claim: rounds
 (and with them host polls) per pass drop ~Wx with W workers at equal
@@ -78,10 +79,12 @@ from repro.core.distributed import (
     cursor_pspecs,
     make_pump_ingest_round,
     make_pump_round,
+    make_stats_step,
     window_pspecs,
 )
 from repro.core.multiquery import (
     MultiQuerySpec,
+    RoundFns,
     SampleCursor,
     SharedCountsScheduler,
 )
@@ -137,6 +140,8 @@ class DistributedPump(SharedCountsScheduler):
         self.mesh = mesh
         self.data_axes = tuple(data_axes)
         self.model_axis = model_axis
+        self._histogram_impl = histogram_impl
+        self._onehot_dtype = onehot_dtype
         for ax in self.data_axes + (model_axis,):
             if ax not in mesh.shape:
                 raise ValueError(f"mesh has no axis {ax!r}; axes are {dict(mesh.shape)}")
@@ -188,18 +193,26 @@ class DistributedPump(SharedCountsScheduler):
             telemetry=telemetry,
             plans=plans,
         )
-        # The shard rounds key their plans on the per-worker kernel
-        # shapes (vz_shard rows), not the scheduler-level full V_Z —
-        # resolve separately unless the caller pinned a pair explicitly.
-        self._round = make_pump_round(
-            mesh, spec, blocks_per_worker=self._blocks_per_worker,
-            data_axes=self.data_axes, model_axis=model_axis, policy=self.policy,
-            histogram_impl=histogram_impl, onehot_dtype=onehot_dtype, plans=plans,
-        )
-        self._ingest_only_round = make_pump_ingest_round(
-            mesh, spec, blocks_per_worker=self._blocks_per_worker,
+
+    def _make_rounds(self, mesh, model_axis: str, plans) -> RoundFns:
+        """The per-worker pump rounds and the mesh statistics step. The
+        shard rounds key their plans on the per-worker kernel shapes
+        (vz_shard rows), not the scheduler-level full V_Z — resolved
+        separately unless the caller pinned a pair explicitly."""
+        kw = dict(
             data_axes=self.data_axes, model_axis=model_axis,
-            histogram_impl=histogram_impl, onehot_dtype=onehot_dtype, plans=plans,
+            histogram_impl=self._histogram_impl, onehot_dtype=self._onehot_dtype,
+            plans=plans,
+        )
+        return RoundFns(
+            round=make_pump_round(
+                mesh, self.spec, blocks_per_worker=self._blocks_per_worker,
+                policy=self.policy, **kw,
+            ),
+            ingest=make_pump_ingest_round(
+                mesh, self.spec, blocks_per_worker=self._blocks_per_worker, **kw
+            ),
+            stats=make_stats_step(mesh, self.spec, model_axis=model_axis, plans=plans),
         )
 
     # -- cursor placement / snapshot layout --------------------------------
@@ -330,9 +343,3 @@ class DistributedPump(SharedCountsScheduler):
         return self._assemble(
             [s.fetch(p, pad_to=pad) for s, p in zip(self.shards, pieces)]
         )
-
-    def _dispatch_round(self, wd: WindowData) -> None:
-        self.state, self.cursor = self._round(self.state, self.cursor, wd)
-
-    def _dispatch_ingest(self, wd: WindowData) -> None:
-        self.state, self.cursor = self._ingest_only_round(self.state, self.cursor, wd)

@@ -102,9 +102,9 @@ def make_pipeline_forward(
         # batch replicated across pods, sharded over data inside the pod)
     )
     out_specs = P(data_axes)
-    from repro.core.distributed import shard_map_compat
-
-    return shard_map_compat(pipelined, mesh, in_specs=in_specs, out_specs=out_specs)
+    return jax.shard_map(
+        pipelined, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 def transformer_stage_fn(layer_fn: Callable, layers_per_stage: int):

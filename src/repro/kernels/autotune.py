@@ -81,7 +81,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import metrics, ref
-from repro.kernels.histogram import histogram_pallas, histogram_with_rowsums_pallas
+from repro.kernels.histogram import (
+    SAMPLE_TILE_MULTIPLE,
+    histogram_pallas,
+    histogram_with_rowsums_pallas,
+)
 
 # Single-block V_X bound of the Q=1 kernel the "unrolled" variant stacks.
 _UNROLLED_MAX_VX = metrics.MAX_SINGLE_BLOCK_VX
@@ -135,7 +139,7 @@ class TauPlan:
 
     variant: str = "batched"  # "batched" | "unrolled" | "xla"
     z_tile: int = 256  # Pallas candidate-row tile
-    x_tile: int = 4096  # Pallas lane tile (single-sweep bound)
+    x_tile: int = metrics._X_TILE  # Pallas lane tile (single-sweep bound)
     sweeps: int = 0  # 0 = auto (by padded V_X), 1 = single, 2 = forced two-sweep
     lowprec: bool = False  # uint16 counts traffic behind the overflow gate
 
@@ -155,12 +159,15 @@ class IngestPlan:
     """One ingest (histogram + row-sums) dispatch decision."""
 
     fused: bool = True  # one fused pass vs histogram + separate reduction
-    s_tile: int = 512  # Pallas sample tile
+    s_tile: int = 2048  # Pallas sample tile, whole (8, 128) int32 tiles
     z_tile: int = 256  # Pallas candidate-row tile
 
     def validate(self) -> None:
-        if self.s_tile < 8:
-            raise ValueError(f"need s_tile >= 8, got {self.s_tile}")
+        if self.s_tile <= 0 or self.s_tile % SAMPLE_TILE_MULTIPLE:
+            raise ValueError(
+                f"s_tile must be a positive multiple of {SAMPLE_TILE_MULTIPLE}, "
+                f"got {self.s_tile}"
+            )
         if self.z_tile < 8:
             raise ValueError(f"need z_tile >= 8, got {self.z_tile}")
 
@@ -259,8 +266,12 @@ def _tau_inner(plan: TauPlan, *, engine: str, interpret: bool,
 
 def _tau_usable(plan: TauPlan, *, engine: str, v_x: int) -> bool:
     """Whether ``plan`` can run at all for this engine/shape (the
-    single-query Pallas kernel rejects V_X past one VMEM block)."""
+    single-query Pallas kernel rejects V_X past one VMEM block, and
+    Mosaic has no uint16 -> f32 cast, so the Pallas kernels take no
+    lowprec counts)."""
     if engine == "pallas" and plan.variant == "unrolled" and v_x > _UNROLLED_MAX_VX:
+        return False
+    if engine == "pallas" and plan.variant != "xla" and plan.lowprec:
         return False
     if plan.sweeps == 1 and max(128, -(-v_x // 128) * 128) > plan.x_tile:
         return False  # forced single-sweep cannot cover a lane-tiled V_X
@@ -582,17 +593,21 @@ def _measure(fn: Callable, args: tuple, *, reps: int) -> float:
 def tau_candidates(engine: str, v_z: int, v_x: int, q: int) -> list:
     """The candidate space for one tau key. CPU: variants x lowprec
     (the ref path has no tiling). TPU: additionally tile sizes and the
-    forced two-sweep phase for the batched kernel."""
+    forced two-sweep phase for the batched kernel — tiles whose blocks
+    fit a v5e's scoped VMEM at Q = 8 (a (256, 4096) two-sweep block
+    does not)."""
     cands = []
     for variant in TAU_VARIANTS:
         base = TauPlan(variant=variant)
         if not _tau_usable(base, engine=engine, v_x=v_x):
             continue
         cands.append(base)
-        cands.append(dataclasses.replace(base, lowprec=True))
+        low = dataclasses.replace(base, lowprec=True)
+        if _tau_usable(low, engine=engine, v_x=v_x):
+            cands.append(low)
         if engine == "pallas" and variant == "batched":
             for z_tile in (128, 256, 512):
-                for x_tile in (1024, 2048, 4096):
+                for x_tile in (256, 512, 1024):
                     for sweeps in (0, 2):
                         c = TauPlan(variant="batched", z_tile=z_tile,
                                     x_tile=x_tile, sweeps=sweeps)
@@ -604,7 +619,7 @@ def tau_candidates(engine: str, v_z: int, v_x: int, q: int) -> list:
 def ingest_candidates(engine: str, v_z: int, v_x: int) -> list:
     cands = [IngestPlan(fused=True), IngestPlan(fused=False)]
     if engine == "pallas":
-        for s_tile in (256, 512, 1024):
+        for s_tile in (1024, 2048, 4096):
             for z_tile in (128, 256, 512):
                 for fused in (True, False):
                     c = IngestPlan(fused=fused, s_tile=s_tile, z_tile=z_tile)

@@ -8,20 +8,28 @@ a ONE-HOT CONTRACTION that runs on the MXU:
     counts[z, x] = sum_s onehot_z[s, z] * onehot_x[s, x]
                  = (onehot_z)^T @ (onehot_x)
 
-For a tile of S_TILE samples and a V_Z tile of Z_TILE candidates, the
-kernel materializes the two one-hot tiles in VMEM (via broadcasted iota
-compares — no gather) and issues a (Z_TILE x S_TILE) @ (S_TILE x V_X)
-matmul, accumulating over sample tiles into the output block, which
-stays resident in VMEM across the inner grid dimension.
+Layout. Samples arrive lane-dense: the (N,) id vectors are viewed as
+(N/128, 128) rows, and one grid step takes S_TILE/128 such rows (a
+multiple of 8, so every block is a whole number of (8, 128) int32
+tiles). Per row, the kernel builds the two transposed one-hot tiles in
+VMEM — (Z_TILE, 128) and (VX_PAD, 128), candidate / bin ids along
+sublanes compared against the row's 128 lanes, no gather and no
+relayout — and issues a (Z_TILE x 128) @ (128 x VX_PAD) matmul that
+contracts the 128 samples, accumulating into the output block, which
+stays resident in VMEM across the inner (sample) grid dimension. The
+output is lane-padded to VX_PAD = V_X rounded up to 128 (the MXU fills
+128 output lanes whatever V_X is) and sliced back outside.
 
-Padding convention: z or x entries < 0 never match any iota column, so
+Padding convention: z or x entries < 0 never match any iota row, so
 padded samples contribute zero — no separate mask operand.
 
 `histogram_with_rowsums_pallas` additionally emits the per-candidate
 row-sum delta (the ingest-side ``n_i`` increment) from the SAME pass:
 the counts block is still VMEM-resident after the last sample tile, so
 the lane reduction is free — `ingest` no longer re-streams the full
-delta matrix from HBM for a separate ``jnp.sum(delta, axis=1)``.
+delta matrix from HBM for a separate ``jnp.sum(delta, axis=1)``. The
+row sums leave as a (VZ_PAD, 1) column, a 2-D layout Mosaic and XLA
+agree on for any number of candidate tiles.
 """
 
 from __future__ import annotations
@@ -32,16 +40,20 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["histogram_pallas", "histogram_with_rowsums_pallas"]
+__all__ = ["SAMPLE_TILE_MULTIPLE", "histogram_pallas", "histogram_with_rowsums_pallas"]
 
-# Default tile sizes: S_TILE samples per inner step, Z_TILE candidate rows.
-# VMEM footprint: onehot_z (S,Z) f32 + onehot_x (S,X) f32 + out (Z,X) f32.
-# At S=512, Z=256, X<=2048: 0.5 + 4 + 2 MiB — comfortably inside 16 MiB.
-_S_TILE = 512
+_LANES = 128
+# A sample tile is whole (8, 128) int32 tiles of the (N/128, 128) view.
+SAMPLE_TILE_MULTIPLE = 8 * _LANES
+# Default tile sizes: S_TILE samples per grid step, Z_TILE candidate rows.
+# VMEM footprint: two (S_TILE/128, 128) i32 input blocks, the (Z, 128)
+# and (VX_PAD, 128) f32 one-hots of one row, the (Z, VX_PAD) f32 output
+# block — at S=2048, Z=256, VX_PAD<=2048 well inside the scoped VMEM.
+_S_TILE = 2048
 _Z_TILE = 256
 
 
-def _histogram_kernel(z_ref, x_ref, out_ref, *rows_ref, v_x: int, z_tile: int, num_sb: int):
+def _histogram_kernel(z_ref, x_ref, out_ref, *rows_ref, z_tile: int, vx_pad: int, num_sb: int):
     zb = pl.program_id(0)
     sb = pl.program_id(1)
 
@@ -49,29 +61,30 @@ def _histogram_kernel(z_ref, x_ref, out_ref, *rows_ref, v_x: int, z_tile: int, n
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    z = z_ref[...]  # (S_TILE,) int32
-    x = x_ref[...]  # (S_TILE,) int32
-    s_tile = z.shape[0]
+    # Candidate / bin ids along sublanes; the row's samples along lanes.
+    z_ids = jax.lax.broadcasted_iota(jnp.int32, (z_tile, _LANES), 0) + zb * z_tile
+    x_ids = jax.lax.broadcasted_iota(jnp.int32, (vx_pad, _LANES), 0)
 
-    # One-hot tiles via 2D broadcasted iota (TPU requires >=2D iota).
-    z_cols = jax.lax.broadcasted_iota(jnp.int32, (s_tile, z_tile), 1)
-    x_cols = jax.lax.broadcasted_iota(jnp.int32, (s_tile, v_x), 1)
-    z_local = z - zb * z_tile
-    onehot_z = (z_local[:, None] == z_cols).astype(jnp.float32)
-    onehot_x = (x[:, None] == x_cols).astype(jnp.float32)
+    def row(r, carry):
+        z = z_ref[pl.ds(r, 1), :]  # (1, 128) int32
+        x = x_ref[pl.ds(r, 1), :]
+        onehot_z = (z == z_ids).astype(jnp.float32)  # (Z_TILE, 128)
+        onehot_x = (x == x_ids).astype(jnp.float32)  # (VX_PAD, 128)
+        # (Z_TILE, 128) @ (128, VX_PAD) on the MXU, contracting samples.
+        out_ref[...] += jax.lax.dot_general(
+            onehot_z,
+            onehot_x,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        return carry
 
-    # (Z_TILE, S_TILE) @ (S_TILE, V_X) on the MXU.
-    out_ref[...] += jax.lax.dot_general(
-        onehot_z,
-        onehot_x,
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    jax.lax.fori_loop(0, z_ref.shape[0], row, 0)
 
     if rows_ref:  # fused row-sum output: reduce the still-resident block
         @pl.when(sb == num_sb - 1)
         def _rows():
-            rows_ref[0][...] = jnp.sum(out_ref[...], axis=1)
+            rows_ref[0][...] = jnp.sum(out_ref[...], axis=1, keepdims=True)
 
 
 def _histogram_call(
@@ -85,42 +98,56 @@ def _histogram_call(
     with_rowsums: bool,
     interpret: bool,
 ):
+    if s_tile <= 0 or s_tile % SAMPLE_TILE_MULTIPLE:
+        raise ValueError(
+            f"s_tile must be a positive multiple of {SAMPLE_TILE_MULTIPLE}, got {s_tile}"
+        )
+    if z_tile < v_z and z_tile % 8:
+        raise ValueError(f"z_tile must be a multiple of 8 below V_Z, got {z_tile}")
+    z_idx = z_idx.reshape(-1)
+    x_idx = x_idx.reshape(-1)
     n = z_idx.shape[0]
     # Clamp out-of-range ids to the "never matches" value -1.
     z_idx = jnp.where((z_idx >= 0) & (z_idx < v_z), z_idx, -1).astype(jnp.int32)
     x_idx = jnp.where((x_idx >= 0) & (x_idx < v_x), x_idx, -1).astype(jnp.int32)
 
-    s_tile = min(s_tile, max(8, n))
-    n_pad = -(-n // s_tile) * s_tile
+    # (N,) -> (rows, 128); a window shorter than one tile is one block.
+    rows = max(1, -(-n // _LANES))
+    s_rows = min(s_tile // _LANES, rows)
+    rows_pad = -(-rows // s_rows) * s_rows
+    n_pad = rows_pad * _LANES
     if n_pad != n:
         z_idx = jnp.pad(z_idx, (0, n_pad - n), constant_values=-1)
         x_idx = jnp.pad(x_idx, (0, n_pad - n), constant_values=-1)
+    z_idx = z_idx.reshape(rows_pad, _LANES)
+    x_idx = x_idx.reshape(rows_pad, _LANES)
 
     z_tile = min(z_tile, v_z)
     vz_pad = -(-v_z // z_tile) * z_tile
+    vx_pad = -(-v_x // _LANES) * _LANES
 
-    grid = (vz_pad // z_tile, n_pad // s_tile)
-    out_shape = [jax.ShapeDtypeStruct((vz_pad, v_x), jnp.float32)]
-    out_specs = [pl.BlockSpec((z_tile, v_x), lambda zb, sb: (zb, 0))]
+    grid = (vz_pad // z_tile, rows_pad // s_rows)
+    out_shape = [jax.ShapeDtypeStruct((vz_pad, vx_pad), jnp.float32)]
+    out_specs = [pl.BlockSpec((z_tile, vx_pad), lambda zb, sb: (zb, 0))]
     if with_rowsums:
-        out_shape.append(jax.ShapeDtypeStruct((vz_pad,), jnp.float32))
-        out_specs.append(pl.BlockSpec((z_tile,), lambda zb, sb: (zb,)))
+        out_shape.append(jax.ShapeDtypeStruct((vz_pad, 1), jnp.float32))
+        out_specs.append(pl.BlockSpec((z_tile, 1), lambda zb, sb: (zb, 0)))
     outs = pl.pallas_call(
         functools.partial(
-            _histogram_kernel, v_x=v_x, z_tile=z_tile, num_sb=grid[1]
+            _histogram_kernel, z_tile=z_tile, vx_pad=vx_pad, num_sb=grid[1]
         ),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((s_tile,), lambda zb, sb: (sb,)),
-            pl.BlockSpec((s_tile,), lambda zb, sb: (sb,)),
+            pl.BlockSpec((s_rows, _LANES), lambda zb, sb: (sb, 0)),
+            pl.BlockSpec((s_rows, _LANES), lambda zb, sb: (sb, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
     )(z_idx, x_idx)
     if with_rowsums:
-        return outs[0][:v_z], outs[1][:v_z]
-    return outs[0][:v_z]
+        return outs[0][:v_z, :v_x], outs[1][:v_z, 0]
+    return outs[0][:v_z, :v_x]
 
 
 def histogram_pallas(
@@ -136,7 +163,8 @@ def histogram_pallas(
     """(V_Z, V_X) float32 histogram of (z, x) sample pairs.
 
     Entries with z_idx < 0 or x_idx < 0 (or >= bounds) are dropped.
-    Inputs are padded to tile multiples internally.
+    Inputs are padded to tile multiples internally; ``s_tile`` is a
+    multiple of `SAMPLE_TILE_MULTIPLE`.
     """
     return _histogram_call(
         z_idx, x_idx, v_z=v_z, v_x=v_x, s_tile=s_tile, z_tile=z_tile,

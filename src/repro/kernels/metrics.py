@@ -77,9 +77,12 @@ __all__ = [
 ]
 
 _Z_TILE = 256
-# Lane-tile width: one (Z_TILE x X_TILE) f32 block must fit VMEM with
-# headroom (256 x 4096 x 4B = 4 MiB). V_X beyond this is lane-tiled.
-_X_TILE = 4096
+# Lane-tile width: one (Z_TILE x X_TILE) f32 block, double-buffered,
+# plus the per-query score temporaries of the two-sweep kernel must fit
+# the scoped VMEM (256 x 1024 x 4B = 1 MiB a block; at X_TILE = 4096 the
+# Q=8 two-sweep kernel runs out of VMEM on a v5e). V_X beyond this is
+# lane-tiled.
+_X_TILE = 1024
 # Single-block V_X bound for the Q=1 (unrolled) kernel form.
 MAX_SINGLE_BLOCK_VX = 4096
 

@@ -82,6 +82,7 @@ previously-baked plans.
 from __future__ import annotations
 
 import functools
+import re
 from typing import Literal, Union
 
 import jax
@@ -100,6 +101,7 @@ __all__ = [
     "l1_distance_multi",
     "anyactive",
     "default_impl",
+    "tpu_kernels_in",
 ]
 
 Impl = Literal["auto", "pallas", "ref"]
@@ -115,6 +117,16 @@ def default_impl() -> str:
 
 def _resolve(impl: Impl) -> str:
     return default_impl() if impl == "auto" else impl
+
+
+def tpu_kernels_in(hlo_text: str) -> set:
+    """Names of the Pallas TPU kernels (``tpu_custom_call``) in the text
+    of a compiled program (``jit(f).lower(...).compile().as_text()``)."""
+    return {
+        re.search(r"%([A-Za-z_]\w*?)(?:\.\d+)? = ", line).group(1)
+        for line in hlo_text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    }
 
 
 @functools.partial(jax.jit, static_argnames=("v_z", "v_x", "impl", "interpret", "onehot_dtype"))

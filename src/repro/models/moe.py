@@ -175,13 +175,12 @@ def moe_ffn_local(
     fspec = P(None, None, tp)  # (E, D, F) — ff TP-sharded, D replicated
     dspec = P(None, tp, None)  # (E, F, D)
     out_specs = (x_spec, {k: P() for k in ("load_balance_loss", "router_z_loss", "drop_frac")})
-    from repro.core.distributed import shard_map_compat
-
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         inner,
-        mesh,
+        mesh=mesh,
         in_specs=(P(None, None), fspec, fspec, dspec, x_spec),
         out_specs=out_specs,
+        check_vma=False,
     )
     return fn(params["router"], params["w_gate"], params["w_up"], params["w_down"], x)
 

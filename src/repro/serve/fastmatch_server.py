@@ -40,11 +40,12 @@ device by at most ``poll_every - 1`` windows (bounded staleness; the
 generalized paper-Sec 4.2 relaxation) in exchange for ~``poll_every``x
 fewer device↔host round-trips (`scheduler.host_syncs`). With ``mesh``
 given, the shared counts matrix is candidate-sharded over the mesh's
-model axis, so one server spans a data-parallel mesh; add ``pump=True``
+model axis (V_Z must divide by that axis's size, else construction
+raises ValueError), so one server spans a data-parallel mesh; add ``pump=True``
 to replace the single gathered window stream with one `ShardedSource`
 stream per data-parallel worker feeding the explicit-collective pump
 round — ingest bandwidth then scales with worker count (see the
-GSPMD-vs-pump dispatch table and per-round collective inventory in
+stream-vs-pump dispatch table and per-round collective inventory in
 `repro.core.pump`).
 
 Per-query `MatchResult` counters (blocks/tuples/rounds) measure what
@@ -246,7 +247,7 @@ class MatchServer:
         # pump: with mesh given, serve through the data-parallel
         # `repro.core.pump.DistributedPump` — one ShardedSource window
         # stream per worker along ``data_axes`` feeding the explicit
-        # shard_map round — instead of the GSPMD fused round over one
+        # shard_map round — instead of the single-stream round over one
         # global stream (see the dispatch table in core/pump.py).
         # Requires the raw BlockedDataset. ``prefetch`` overlaps the
         # next-window gather with the current round — per worker in

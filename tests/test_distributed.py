@@ -107,9 +107,9 @@ class TestDistributedHistSim:
         assert res["ok"], res
 
     def test_mesh_server_matches_single_device(self):
-        """MatchServer(mesh=...) — counts candidate-sharded via GSPMD —
-        must resolve the same queries to the same matching sets as the
-        unsharded server."""
+        """MatchServer(mesh=...) — counts candidate-sharded, explicit
+        shard_map rounds — must resolve the same queries to the same
+        matching sets as the unsharded server."""
         out = _run_subprocess("""
             import jax, jax.numpy as jnp, numpy as np, json
             from jax.sharding import Mesh
@@ -141,6 +141,39 @@ class TestDistributedHistSim:
             print(json.dumps({"ok": bool(ok),
                               "tuples": srv.metrics["total_tuples_read"],
                               "tuples_ref": ref.metrics["total_tuples_read"]}))
+        """)
+        res = json.loads(out.strip().splitlines()[-1])
+        assert res["ok"], res
+
+    def test_mesh_refuses_candidates_the_model_axis_does_not_divide(self):
+        """V_Z = 64 on a model axis of 3: the mesh server, the mesh
+        scheduler and the pump all refuse at construction, naming V_Z."""
+        out = _run_subprocess("""
+            import jax, numpy as np, json
+            from jax.sharding import Mesh
+            from repro.core import multiquery as mq
+            from repro.core.pump import DistributedPump
+            from repro.data.layout import block_layout
+            from repro.data.synth import SynthSpec, make_dataset
+            from repro.serve.fastmatch_server import MatchServer
+
+            ds = make_dataset(SynthSpec(v_z=64, v_x=16, num_tuples=20_000, k=3, seed=3))
+            blocked = block_layout(ds.z, ds.x, v_z=64, v_x=16, block_size=256, seed=3)
+            spec = mq.MultiQuerySpec(v_z=64, v_x=16, max_queries=2)
+            mesh = Mesh(np.array(jax.devices()[:6]).reshape(2, 3), ("data", "model"))
+            builds = dict(
+                server=lambda: MatchServer(blocked, max_queries=2, mesh=mesh),
+                scheduler=lambda: mq.SharedCountsScheduler(blocked, spec, mesh=mesh),
+                pump=lambda: DistributedPump(blocked, spec, mesh=mesh),
+            )
+            refused = {}
+            for name, build in builds.items():
+                try:
+                    build()
+                    refused[name] = False
+                except ValueError as e:
+                    refused[name] = "V_Z=64 must divide by model axis size 3" in str(e)
+            print(json.dumps(dict(ok=all(refused.values()), refused=refused)))
         """)
         res = json.loads(out.strip().splitlines()[-1])
         assert res["ok"], res

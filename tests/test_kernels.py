@@ -34,9 +34,9 @@ class TestHistogramKernel:
         want = ref.histogram_ref(jnp.asarray(z), jnp.asarray(x), v_z=v_z, v_x=v_x)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want))
 
-    @pytest.mark.parametrize("s_tile,z_tile", [(64, 32), (512, 256), (128, 1024)])
+    @pytest.mark.parametrize("s_tile,z_tile", [(1024, 32), (2048, 256), (1024, 1024)])
     def test_tile_sweep(self, s_tile, z_tile, rng):
-        v_z, v_x, n = 200, 30, 1500
+        v_z, v_x, n = 200, 30, 5000  # several sample tiles at every s_tile
         z = rng.integers(0, v_z, size=n).astype(np.int32)
         x = rng.integers(0, v_x, size=n).astype(np.int32)
         got = histogram_pallas(

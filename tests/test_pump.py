@@ -1,21 +1,22 @@
-"""Golden equivalence: data-parallel pump vs the single-stream GSPMD round.
+"""Golden equivalence: the mesh rounds vs the single-device round.
 
 `repro.core.pump.DistributedPump` replaces the scheduler's one gathered
 window stream with one `ShardedSource` stream per mesh worker feeding
 the explicit shard_map pump round. This suite pins the refactor to the
 single-stream semantics, in the spirit of `test_device_loop.py`:
 
-  * LOCKSTEP — driven with the same global windows, a pump round must
-    be bit-identical to the GSPMD `fused_round` on integer counts:
+  * LOCKSTEP — driven with the same global windows, a pump round and
+    a round of the candidate-sharded single-stream mesh scheduler must
+    both be bit-identical to the single-device `fused_round` (no mesh):
     counts / n / tau / read_mask / cursor counters for mesh shapes
     sweeping data x model in {1, 2, 8} x {1, 2}, with mid-stream
     admission AND retirement inside the drive (delta_upper is bit-exact
-    with the model axis unsharded and allclose under model sharding —
-    the GSPMD reference splits that V_Z reduction across shards);
+    with the model axis unsharded and allclose under model sharding);
   * the full pump() loop (per-worker visit interleaving) must resolve
     the same queries to the same matching sets as the unsharded server,
     and `prefetch=True` must not change a single bit;
-  * the exact-completion fallback must land on identical true counts.
+  * the exact-completion fallback of both mesh paths must land on the
+    single-device scheduler's true counts.
 
 Multi-device cases run in subprocesses with their own XLA_FLAGS (the
 main test process must keep 1 device); the single-worker TestPumpOnOneDevice
@@ -70,75 +71,80 @@ _DATASET = textwrap.dedent("""
 @pytest.mark.slow
 class TestPumpGolden:
     def test_lockstep_bit_identical_across_mesh_shapes(self):
-        """Same global windows through pump and GSPMD scheduler: every
-        per-round quantity must match bit for bit — including a query
-        admitted mid-drive and queries retired mid-drive — for worker
-        counts 1/2/8 and model shardings 1/2."""
+        """Same global windows through the single-device scheduler (the
+        reference, no mesh), the candidate-sharded mesh scheduler and
+        the pump: every per-round quantity of both mesh paths must match
+        the reference bit for bit — including a query admitted mid-drive
+        and queries retired mid-drive — for worker counts 1/2/8 and model
+        shardings 1/2."""
         out = _run_subprocess(_DATASET + textwrap.dedent("""
             K, EPS, DELTA = 5, 0.08, 0.02
+
+            def same(a, b):
+                return bool(np.array_equal(np.asarray(a), np.asarray(b)))
+
+            def compare(ref, s, msz):
+                return dict(
+                    counts=same(ref.state.counts, s.state.counts),
+                    n=same(ref.state.n, s.state.n),
+                    tau=same(ref.state.tau, s.state.tau),
+                    # delta_upper sums delta_i over V_Z: bit-exact with the
+                    # model axis unsharded, allclose when it is sharded.
+                    du=same(ref.state.delta_upper, s.state.delta_upper)
+                       if msz == 1 else
+                       bool(np.allclose(np.asarray(ref.state.delta_upper),
+                                        np.asarray(s.state.delta_upper),
+                                        rtol=1e-5, atol=1e-7)),
+                    mask=same(ref.read_mask, s.read_mask),
+                    counters=(ref.blocks_read, ref.blocks_considered,
+                              ref.tuples_read, ref.rounds)
+                             == (s.blocks_read, s.blocks_considered,
+                                 s.tuples_read, s.rounds),
+                    live=sorted(ref.tickets) == sorted(s.tickets),
+                )
 
             def drive(shape):
                 dsz, msz = shape
                 mesh = Mesh(np.array(jax.devices()[: dsz * msz]).reshape(dsz, msz),
                             ("data", "model"))
-                ref = mq.SharedCountsScheduler(
-                    blocked, spec, window=32, seed=0, start_block=7, mesh=mesh)
-                pmp = DistributedPump(
-                    blocked, spec, mesh=mesh, window=32, seed=0, start_block=7)
+                kw = dict(window=32, seed=0, start_block=7)
+                ref = mq.SharedCountsScheduler(blocked, spec, **kw)
+                runs = dict(
+                    mesh=mq.SharedCountsScheduler(blocked, spec, mesh=mesh, **kw),
+                    pump=DistributedPump(blocked, spec, mesh=mesh, **kw),
+                )
+                scheds = [ref, *runs.values()]
                 for t in targets[:3]:
-                    ref.admit(t, k=K, eps=EPS, delta=DELTA)
-                    pmp.admit(t, k=K, eps=EPS, delta=DELTA)
+                    for s in scheds:
+                        s.admit(t, k=K, eps=EPS, delta=DELTA)
                 # shuffled windows so every round straddles worker ranges
                 order = np.random.default_rng(1).permutation(blocked.num_blocks)
-                checks = []
+                checks = {name: [] for name in runs}
                 for r in range(12):
                     if r == 3:  # mid-stream admission into the free slot
-                        ref.admit(targets[3], k=3, eps=0.1, delta=DELTA)
-                        pmp.admit(targets[3], k=3, eps=0.1, delta=DELTA)
+                        for s in scheds:
+                            s.admit(targets[3], k=3, eps=0.1, delta=DELTA)
                     win = order[r * 32 : (r + 1) * 32]
-                    ref.run_window(win)
-                    pmp.run_window(win)
-                    ref._poll_terminated()  # mid-stream retirement
-                    pmp._poll_terminated()
-                    checks.append(dict(
-                        counts=bool(np.array_equal(np.asarray(ref.state.counts),
-                                                   np.asarray(pmp.state.counts))),
-                        n=bool(np.array_equal(np.asarray(ref.state.n),
-                                              np.asarray(pmp.state.n))),
-                        tau=bool(np.array_equal(np.asarray(ref.state.tau),
-                                                np.asarray(pmp.state.tau))),
-                        # delta_upper sums delta_i over V_Z: with the model
-                        # axis sharded the GSPMD reference lets XLA split
-                        # that reduction across shards, so its low bits
-                        # differ from the pump's replicated tail (which
-                        # reduces on one device, after the all-gather).
-                        # Bit-exact when model=1; allclose when sharded.
-                        du=bool(np.array_equal(
-                                    np.asarray(ref.state.delta_upper),
-                                    np.asarray(pmp.state.delta_upper))
-                                if msz == 1 else
-                                np.allclose(
-                                    np.asarray(ref.state.delta_upper),
-                                    np.asarray(pmp.state.delta_upper),
-                                    rtol=1e-5, atol=1e-7)),
-                        mask=bool(np.array_equal(ref.read_mask, pmp.read_mask)),
-                        counters=(ref.blocks_read, ref.blocks_considered,
-                                  ref.tuples_read, ref.rounds)
-                                 == (pmp.blocks_read, pmp.blocks_considered,
-                                     pmp.tuples_read, pmp.rounds),
-                        live=sorted(ref.tickets) == sorted(pmp.tickets),
-                    ))
-                retired = len(ref.outcomes)
-                ids_equal = all(
-                    np.array_equal(ref.outcomes[q].ids, pmp.outcomes[q].ids)
-                    for q in ref.outcomes)
-                flat = {k: all(c[k] for c in checks) for k in checks[0]}
-                flat.update(retired=retired,
-                            same_retired=set(ref.outcomes) == set(pmp.outcomes),
-                            ids=ids_equal)
-                return flat
+                    for s in scheds:
+                        s.run_window(win)
+                        s._poll_terminated()  # mid-stream retirement
+                    for name, s in runs.items():
+                        checks[name].append(compare(ref, s, msz))
+                out = {}
+                for name, s in runs.items():
+                    flat = {k: all(c[k] for c in checks[name]) for k in checks[name][0]}
+                    flat.update(
+                        retired=len(ref.outcomes),
+                        same_retired=set(ref.outcomes) == set(s.outcomes),
+                        ids=all(np.array_equal(ref.outcomes[q].ids, s.outcomes[q].ids)
+                                for q in ref.outcomes),
+                    )
+                    out[name] = flat
+                return out
 
-            results = {str(s): drive(s) for s in [(1, 1), (2, 1), (8, 1), (2, 2), (4, 2)]}
+            results = {f"{s}/{name}": r
+                       for s in [(1, 1), (2, 1), (8, 1), (2, 2), (4, 2)]
+                       for name, r in drive(s).items()}
             ok = all(all(v for k, v in r.items() if k != "retired")
                      for r in results.values())
             # the drive must actually exercise retirement somewhere
@@ -195,29 +201,34 @@ class TestPumpGolden:
 
     def test_exact_completion_lockstep(self):
         """An unreachable bound forces the exact fallback: the pump's
-        per-worker completion chunks must land on the same true counts
-        and the same exact answers as the single-stream completion."""
+        per-worker completion chunks and the mesh scheduler's completion
+        must land on the same true counts and the same exact answers as
+        the single-device completion."""
         out = _run_subprocess(_DATASET + textwrap.dedent("""
             mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ("data", "model"))
-            ref = mq.SharedCountsScheduler(
-                blocked, spec, window=32, seed=0, start_block=3, mesh=mesh)
-            pmp = DistributedPump(
-                blocked, spec, mesh=mesh, window=32, seed=0, start_block=3)
-            for s in (ref, pmp):
+            kw = dict(window=32, seed=0, start_block=3)
+            ref = mq.SharedCountsScheduler(blocked, spec, **kw)
+            runs = dict(
+                mesh=mq.SharedCountsScheduler(blocked, spec, mesh=mesh, **kw),
+                pump=DistributedPump(blocked, spec, mesh=mesh, **kw),
+            )
+            scheds = [ref, *runs.values()]
+            for s in scheds:
                 s.admit(targets[0], k=3, eps=0.02, delta=1e-9)
             order = np.random.default_rng(2).permutation(blocked.num_blocks)
             for r in range(4):
                 win = order[r * 32 : (r + 1) * 32]
-                ref.run_window(win); pmp.run_window(win)
-            ref.complete_remaining(); pmp.complete_remaining()
-            eq = dict(
-                counts=bool(np.array_equal(np.asarray(ref.state.counts),
-                                           np.asarray(pmp.state.counts))),
-                n=bool(np.array_equal(np.asarray(ref.state.n), np.asarray(pmp.state.n))),
-                tau=bool(np.array_equal(np.asarray(ref.state.tau),
-                                        np.asarray(pmp.state.tau))),
-                all_read=bool(ref.read_mask.all() and pmp.read_mask.all()),
-            )
+                for s in scheds:
+                    s.run_window(win)
+            for s in scheds:
+                s.complete_remaining()
+            eq = {}
+            for name, s in runs.items():
+                for leaf in ("counts", "n", "tau"):
+                    eq[f"{name}_{leaf}"] = bool(np.array_equal(
+                        np.asarray(getattr(ref.state, leaf)),
+                        np.asarray(getattr(s.state, leaf))))
+            eq["all_read"] = all(bool(s.read_mask.all()) for s in scheds)
             eq["ok"] = all(eq.values())
             print(json.dumps(eq))
         """))

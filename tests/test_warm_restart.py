@@ -347,7 +347,7 @@ class TestReshardedRestore:
     def test_pump_reshard_8_to_4_workers(self, tmp_path):
         """Pump-mode elastic restart: a cache checkpointed under an
         8-worker pump restores into a 4-worker pump (and into the
-        single-stream GSPMD server — snapshots are global, not
+        single-stream mesh server — snapshots are global, not
         per-worker) with bit-identical counts/read_mask/counters, and a
         fresh query covered by the warm cache answers with bit-identical
         counts/tau/result on every restored width. (A query that must
