@@ -199,8 +199,8 @@ class _CostAccount:
 
 def _timer_call_residual() -> float:
     """Per-window cost of what the wrappers cannot see: the bare
-    `perf_counter` pairs in the pump's gather timing and the prefetch
-    worker/consumer, plus their list appends. Charged as 8 timer calls
+    `perf_counter` pairs in the prefetch worker/consumer, plus their
+    list appends. Charged as 8 timer calls
     + 3 appends per window — a rounded-UP census (the off arm pays some
     of these branches too), measured here rather than assumed."""
     sink: list = []

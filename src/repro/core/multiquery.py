@@ -95,6 +95,7 @@ from repro.core.policies import mark_window
 from repro.io import BlockSource, WindowData, as_block_source
 from repro.io.faults import WindowQuarantined, find_resilient
 from repro.kernels import autotune, ops
+from repro.obs.spans import span, spanned
 from repro.obs.telemetry import Telemetry
 
 __all__ = [
@@ -856,41 +857,6 @@ def _metric_eps_np(n: float, delta_i: float, v_x: int, metric: str) -> float:
     raise ValueError(f"unknown metric {metric!r}")
 
 
-class _BatchAcc:
-    """Host-side wall-time accumulators for one poll's round batch.
-
-    Filled between polls (two `perf_counter` reads per window — the only
-    telemetry cost off the poll boundary), drained into one
-    ``round_batch`` trace event at each poll.
-    """
-
-    __slots__ = ("windows", "gather_s", "dispatch_s", "sync_s")
-
-    def __init__(self):
-        self.reset()
-
-    def reset(self) -> None:
-        self.windows = 0
-        self.gather_s = 0.0
-        self.dispatch_s = 0.0
-        self.sync_s = 0.0
-
-
-def _timed_iter(stream, acc: _BatchAcc):
-    """Yield from ``stream`` accumulating per-window gather wall time
-    (time spent waiting on the source — with `PrefetchSource` underneath
-    this is the residual stall, not the full fetch cost)."""
-    it = iter(stream)
-    while True:
-        t0 = time.perf_counter()
-        try:
-            wd = next(it)
-        except StopIteration:
-            return
-        acc.gather_s += time.perf_counter() - t0
-        yield wd
-
-
 class SharedCountsScheduler:
     """The FastMatch execution loop over a shared counts matrix.
 
@@ -1029,6 +995,7 @@ class SharedCountsScheduler:
         self.total_tuples = int(np.sum(np.asarray(source.tuples_per_block, np.int64)))
         self.budget_exhausted = False
         self.host_syncs = 0  # number of device->host polls performed
+        self.host_sync_bytes = 0  # bytes those polls copied to the host
         # polls made by the window loop itself (pump/run_window), i.e.
         # the steady-state cadence poll_every controls — excludes the
         # per-query fixed polls at admission
@@ -1068,9 +1035,6 @@ class SharedCountsScheduler:
             self._c_quarantined = reg.counter(
                 "fastmatch_blocks_quarantined_total",
                 "blocks dropped from the probe set after I/O quarantine")
-            self._h_batch = reg.histogram(
-                "fastmatch_round_batch_seconds",
-                help="host wall per round batch (gather+dispatch+sync)")
             self._h_q_tuples = reg.histogram(
                 "fastmatch_query_tuples", edges=tuple(float(10 ** e) for e in range(2, 11)),
                 help="tuples read while a query was live (per-query m)")
@@ -1196,7 +1160,8 @@ class SharedCountsScheduler:
         Everything the host loop decides on (termination, budget, pass
         structure, counters) is refreshed here and ONLY here, so
         `host_syncs` is an exact count of device↔host round-trips the
-        loop performs. Retirement snapshots (`retire`) transfer result
+        loop performs, and `host_sync_bytes` of what they copied to the
+        host (the ``nbytes`` of every polled array). Retirement snapshots (`retire`) transfer result
         data per retired query and are not part of the loop cadence.
         """
         # ONE batched poll. Beyond the cursor + bounds the host loop
@@ -1204,10 +1169,13 @@ class SharedCountsScheduler:
         # anytime `peek` assembly and the confidence-trajectory points —
         # pure reads riding the same transfer, so device state and the
         # dispatch sequence are untouched whether or not anyone polls.
-        cursor, delta_upper, tau, n, in_top_k, pruned = jax.device_get(
-            (self.cursor, self.state.delta_upper, self.state.tau,
-             self.state.n, self.state.in_top_k, self.state.pruned)
-        )
+        with span("sync"):
+            polled = jax.device_get(
+                (self.cursor, self.state.delta_upper, self.state.tau,
+                 self.state.n, self.state.in_top_k, self.state.pruned)
+            )
+        cursor, delta_upper, tau, n, in_top_k, pruned = polled
+        self.host_sync_bytes += sum(leaf.nbytes for leaf in jax.tree.leaves(polled))
         self._tel_tau = np.asarray(tau)
         self._tel_n = np.asarray(n)
         self._in_top_k_host = np.asarray(in_top_k)
@@ -1301,21 +1269,15 @@ class SharedCountsScheduler:
                     confidence=max(0.0, 1.0 - d_up),
                 ))
 
-    def _round_batch_extra(self) -> dict:
-        """Extra ``round_batch`` fields — the data-parallel pump adds
-        per-worker gather and assembly timing here."""
-        return {}
-
-    def _emit_round_batch(self, acc: _BatchAcc) -> None:
-        """Drain one poll's timing accumulators into a trace event."""
-        self._h_batch.observe(acc.gather_s + acc.dispatch_s + acc.sync_s)
-        self.telemetry.tracer.emit(
-            "round_batch", windows=acc.windows, rounds=self.rounds,
-            blocks_read=self.blocks_read, tuples_read=self.tuples_read,
-            gather_s=acc.gather_s, dispatch_s=acc.dispatch_s,
-            sync_s=acc.sync_s, **self._round_batch_extra(),
-        )
-        acc.reset()
+    def _emit_round_batch(self, windows: int) -> None:
+        """One ``round_batch`` trace event per loop poll: the windows
+        dispatched since the previous one and the polled totals (their
+        host time is in the profiler trace's spans)."""
+        if self.telemetry is not None:
+            self.telemetry.tracer.emit(
+                "round_batch", windows=windows, rounds=self.rounds,
+                blocks_read=self.blocks_read, tuples_read=self.tuples_read,
+            )
 
     # -- warm-start persistence --------------------------------------------
 
@@ -1452,23 +1414,24 @@ class SharedCountsScheduler:
         if target.shape != (self.spec.v_x,):
             raise ValueError(f"target must have shape ({self.spec.v_x},)")
         q_hat = (target / max(target.sum(), 1e-30)).astype(np.float32)
-        self.state = admit_slot(
-            self.state,
-            jnp.asarray(slot, jnp.int32),
-            jnp.asarray(q_hat),
-            jnp.asarray(k, jnp.int32),
-            jnp.asarray(eps, jnp.float32),
-            jnp.asarray(delta, jnp.float32),
-            spec=self.spec,
-            qtype=jnp.asarray(
-                QTYPE_CLOSENESS if qtype == "closeness" else QTYPE_TOPK,
-                jnp.int32,
-            ),
-            gap=jnp.asarray(gap, jnp.float32),
-        )
-        self.state = self._rounds.stats(self.state)
-        self._sync()  # fresh counters for the ticket + fresh delta_upper
         qid = self._next_qid
+        with span("admit", qid=qid):
+            self.state = admit_slot(
+                self.state,
+                jnp.asarray(slot, jnp.int32),
+                jnp.asarray(q_hat),
+                jnp.asarray(k, jnp.int32),
+                jnp.asarray(eps, jnp.float32),
+                jnp.asarray(delta, jnp.float32),
+                spec=self.spec,
+                qtype=jnp.asarray(
+                    QTYPE_CLOSENESS if qtype == "closeness" else QTYPE_TOPK,
+                    jnp.int32,
+                ),
+                gap=jnp.asarray(gap, jnp.float32),
+            )
+            self.state = self._rounds.stats(self.state)
+            self._sync()  # fresh counters for the ticket + fresh delta_upper
         self._next_qid += 1
         self.tickets[slot] = _Ticket(
             qid=qid,
@@ -1589,58 +1552,59 @@ class SharedCountsScheduler:
         or supervisor deadline); the outcome then carries the honest
         anytime statement of that poll.
         """
-        anytime = self.peek(slot)
-        t = self.tickets.pop(slot)
-        degraded = self.blocks_quarantined > 0
-        if degraded:
-            exact = exact or bool(self.read_mask[~self.quarantined].all())
-        else:
-            exact = exact or bool(self.read_mask.all())
-        view = slot_state(self.state, slot)
-        if t.qtype == "closeness":
-            # The close set, nearest first — in_top_k holds the close
-            # labels for closeness slots (`dev.assign_closeness`); its
-            # size is data-dependent, not k.
-            close = np.flatnonzero(np.asarray(view.in_top_k))
-            order = np.argsort(
-                np.asarray(view.tau)[close], kind="stable"
+        with span("retire", qid=self.tickets[slot].qid):
+            anytime = self.peek(slot)
+            t = self.tickets.pop(slot)
+            degraded = self.blocks_quarantined > 0
+            if degraded:
+                exact = exact or bool(self.read_mask[~self.quarantined].all())
+            else:
+                exact = exact or bool(self.read_mask.all())
+            view = slot_state(self.state, slot)
+            if t.qtype == "closeness":
+                # The close set, nearest first — in_top_k holds the close
+                # labels for closeness slots (`dev.assign_closeness`); its
+                # size is data-dependent, not k.
+                close = np.flatnonzero(np.asarray(view.in_top_k))
+                order = np.argsort(
+                    np.asarray(view.tau)[close], kind="stable"
+                )
+                ids = close[order]
+            else:
+                ids = np.asarray(histsim.top_k_ids(view, t.k))
+            # A query admitted and retired inside one running pass still
+            # saw sampling activity — count that partial pass; a query that
+            # retired before any window ran while it was live saw none.
+            passes = self.passes - t.admit_passes
+            if passes == 0 and self.rounds > t.admit_rounds:
+                passes = 1
+            outcome = QueryOutcome(
+                qid=t.qid,
+                ids=ids,
+                state=view,
+                delta_upper=float(view.delta_upper),
+                exact=exact,
+                terminated=terminated,
+                rounds=self.rounds - t.admit_rounds,
+                passes=passes,
+                blocks_read=self.blocks_read - t.admit_blocks_read,
+                blocks_considered=self.blocks_considered - t.admit_blocks_considered,
+                tuples_read=self.tuples_read - t.admit_tuples_read,
+                wall_time_s=time.perf_counter() - t.admit_time,
+                degraded=degraded,
+                eps_effective=t.eps + (self.eps_inflation if degraded else 0.0),
+                blocks_quarantined=self.blocks_quarantined,
+                qtype=t.qtype,
+                stopped=stopped,
+                stop_reason=stop_reason,
+                anytime=anytime,
             )
-            ids = close[order]
-        else:
-            ids = np.asarray(histsim.top_k_ids(view, t.k))
-        # A query admitted and retired inside one running pass still
-        # saw sampling activity — count that partial pass; a query that
-        # retired before any window ran while it was live saw none.
-        passes = self.passes - t.admit_passes
-        if passes == 0 and self.rounds > t.admit_rounds:
-            passes = 1
-        outcome = QueryOutcome(
-            qid=t.qid,
-            ids=ids,
-            state=view,
-            delta_upper=float(view.delta_upper),
-            exact=exact,
-            terminated=terminated,
-            rounds=self.rounds - t.admit_rounds,
-            passes=passes,
-            blocks_read=self.blocks_read - t.admit_blocks_read,
-            blocks_considered=self.blocks_considered - t.admit_blocks_considered,
-            tuples_read=self.tuples_read - t.admit_tuples_read,
-            wall_time_s=time.perf_counter() - t.admit_time,
-            degraded=degraded,
-            eps_effective=t.eps + (self.eps_inflation if degraded else 0.0),
-            blocks_quarantined=self.blocks_quarantined,
-            qtype=t.qtype,
-            stopped=stopped,
-            stop_reason=stop_reason,
-            anytime=anytime,
-        )
-        anytime.status = "done"
-        anytime.exact = outcome.exact
-        anytime.stopped = stopped
-        anytime.stop_reason = stop_reason
-        self.state = clear_slot(self.state, jnp.asarray(slot, jnp.int32), spec=self.spec)
-        self.outcomes[t.qid] = outcome
+            anytime.status = "done"
+            anytime.exact = outcome.exact
+            anytime.stopped = stopped
+            anytime.stop_reason = stop_reason
+            self.state = clear_slot(self.state, jnp.asarray(slot, jnp.int32), spec=self.spec)
+            self.outcomes[t.qid] = outcome
         if self.telemetry is not None:
             self._c_retired.inc(1)
             self._h_q_tuples.observe(outcome.tuples_read)
@@ -1701,7 +1665,8 @@ class SharedCountsScheduler:
     def _dispatch_round(self, wd: WindowData) -> None:
         """One fused sampling round over prepared window data (no host
         sync — polling is the loop's cadence decision)."""
-        self.state, self.cursor = self._rounds.round(self.state, self.cursor, wd)
+        with span("dispatch"):
+            self.state, self.cursor = self._rounds.round(self.state, self.cursor, wd)
 
     def _fetch_window(self, win: np.ndarray) -> WindowData:
         """Window data for one ad-hoc (global-id) window — the pump
@@ -1711,7 +1676,8 @@ class SharedCountsScheduler:
 
     def _dispatch_ingest(self, wd: WindowData) -> None:
         """One exact-completion ingest round over prepared window data."""
-        self.state, self.cursor = self._rounds.ingest(self.state, self.cursor, wd)
+        with span("dispatch"):
+            self.state, self.cursor = self._rounds.ingest(self.state, self.cursor, wd)
 
     def run_window(self, win: np.ndarray) -> int:
         """Mark one lookahead window against the union active set and
@@ -1722,25 +1688,11 @@ class SharedCountsScheduler:
         if win.size == 0:
             return 0
         before = self.blocks_read
-        if self.telemetry is None:
-            wd = self._fetch_window_or_quarantine(win)
-            if wd is not None:
-                self._dispatch_round(wd)
-            self._sync()
-        else:
-            acc = _BatchAcc()
-            t0 = time.perf_counter()
-            wd = self._fetch_window_or_quarantine(win)
-            acc.gather_s = time.perf_counter() - t0
-            if wd is not None:
-                t0 = time.perf_counter()
-                self._dispatch_round(wd)
-                acc.dispatch_s = time.perf_counter() - t0
-                acc.windows = 1
-            t0 = time.perf_counter()
-            self._sync()
-            acc.sync_s = time.perf_counter() - t0
-            self._emit_round_batch(acc)
+        wd = self._fetch_window_or_quarantine(win)
+        if wd is not None:
+            self._dispatch_round(wd)
+        self._sync()
+        self._emit_round_batch(int(wd is not None))
         self.loop_syncs += 1
         return self.blocks_read - before
 
@@ -1749,7 +1701,8 @@ class SharedCountsScheduler:
         verdict into probe-set removal (None = the window is gone; the
         caller's next poll sees the degraded population)."""
         try:
-            return self._fetch_window(win)
+            with span("fetch"):
+                return self._fetch_window(win)
         except WindowQuarantined as exc:
             self.quarantine_blocks(exc.block_ids, reason="fetch")
             return None
@@ -1763,28 +1716,29 @@ class SharedCountsScheduler:
         one round per chunk — the Scan baseline in `engine.run_engine`
         is exactly this path on a fresh scheduler.
         """
-        self._sync()
-        remaining = np.where(~self.read_mask & ~self.quarantined)[0]
-        if remaining.size == 0:
-            return
-        self.passes += 1
-        t0 = time.perf_counter()
-        windows = 0
-        stream, _ = self._open_pass_stream(remaining)
-        try:
-            for wd in stream:
-                self._dispatch_ingest(wd)
-                windows += 1
-        finally:
-            stream.close()
-        self.state = self._rounds.stats(self.state)
-        self._sync()
-        if self.telemetry is not None:
-            self.telemetry.tracer.emit(
-                "exact_completion", windows=windows, blocks=int(remaining.size),
-                rounds=self.rounds, tuples_read=self.tuples_read,
-                dur_s=time.perf_counter() - t0,
-            )
+        with span("complete"):
+            self._sync()
+            remaining = np.where(~self.read_mask & ~self.quarantined)[0]
+            if remaining.size == 0:
+                return
+            self.passes += 1
+            t0 = time.perf_counter()
+            windows = 0
+            stream, _ = self._open_pass_stream(remaining)
+            try:
+                for wd in spanned(stream, "fetch"):
+                    self._dispatch_ingest(wd)
+                    windows += 1
+            finally:
+                stream.close()
+            self.state = self._rounds.stats(self.state)
+            self._sync()
+            if self.telemetry is not None:
+                self.telemetry.tracer.emit(
+                    "exact_completion", windows=windows, blocks=int(remaining.size),
+                    rounds=self.rounds, tuples_read=self.tuples_read,
+                    dur_s=time.perf_counter() - t0,
+                )
 
     def pump(
         self,
@@ -1810,7 +1764,6 @@ class SharedCountsScheduler:
         lifetime: a long-lived server calling pump per batch gets the
         full budget every time.
         """
-        tel = self.telemetry
         self.budget_exhausted = False
         try:
             self._pump(max_rounds=max_rounds, max_passes=max_passes,
@@ -1834,41 +1787,27 @@ class SharedCountsScheduler:
         # shared counts, before any new window is read.
         self._poll_terminated()
         while self.tickets and self.passes - passes0 < max_passes:
-            pass_order = self.order[
-                ~self.read_mask[self.order] & ~self.quarantined[self.order]
-            ]
+            with span("pass"):
+                pass_order = self.order[
+                    ~self.read_mask[self.order] & ~self.quarantined[self.order]
+                ]
             if pass_order.size == 0:
                 break
             self.passes += 1
             pass_start_rounds = self.rounds
             pass_start_blocks = self.blocks_read
             stream, n_rounds = self._open_pass_stream(pass_order)
-            dispatched = 0
-            if tel is None:
-                acc = None
-                rounds_iter = stream
-            else:
+            if tel is not None:
                 tel.tracer.emit("pass_start", passes=self.passes,
                                 windows=n_rounds, unread=int(pass_order.size))
-                acc = _BatchAcc()
-                rounds_iter = _timed_iter(stream, acc)
+            dispatched = polled = 0
             try:
-                for dispatched, wd in enumerate(rounds_iter, start=1):
-                    if acc is None:
-                        self._dispatch_round(wd)
-                    else:
-                        t0 = time.perf_counter()
-                        self._dispatch_round(wd)
-                        acc.dispatch_s += time.perf_counter() - t0
-                        acc.windows += 1
+                for dispatched, wd in enumerate(spanned(stream, "fetch"), start=1):
+                    self._dispatch_round(wd)
                     if dispatched % self.poll_every == 0 or dispatched == n_rounds:
-                        if acc is None:
-                            self._sync()
-                        else:
-                            t0 = time.perf_counter()
-                            self._sync()
-                            acc.sync_s += time.perf_counter() - t0
-                            self._emit_round_batch(acc)
+                        self._sync()
+                        self._emit_round_batch(dispatched - polled)
+                        polled = dispatched
                         self.loop_syncs += 1
                         self._poll_terminated()
                         if on_round is not None:
@@ -1898,13 +1837,8 @@ class SharedCountsScheduler:
                 # this catch-up poll the zero-progress check below would
                 # judge stale mirrors and the drained quarantine mask
                 # would lag a pass behind.
-                if acc is None:
-                    self._sync()
-                else:
-                    t0 = time.perf_counter()
-                    self._sync()
-                    acc.sync_s += time.perf_counter() - t0
-                    self._emit_round_batch(acc)
+                self._sync()
+                self._emit_round_batch(dispatched - polled)
                 self.loop_syncs += 1
                 self._poll_terminated()
                 if on_round is not None:

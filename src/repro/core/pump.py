@@ -67,7 +67,6 @@ I/O bandwidth.
 
 from __future__ import annotations
 
-import time
 from typing import Optional
 
 import jax
@@ -90,6 +89,7 @@ from repro.core.multiquery import (
 )
 from repro.data.layout import BlockedDataset
 from repro.io import InMemorySource, PrefetchSource, ShardedSource, WindowData
+from repro.obs.spans import spanned
 
 __all__ = ["DistributedPump"]
 
@@ -165,12 +165,6 @@ class DistributedPump(SharedCountsScheduler):
             PrefetchSource(s, telemetry=telemetry) if prefetch else s
             for s in self.shards
         ]
-        # Per-worker ingest-side timing, drained into each round_batch
-        # event (`_round_batch_extra`): how long each worker's next-window
-        # gather took (per-worker I/O skew) + the host assemble/device_put
-        # cost of stacking the W shards.
-        self._worker_gather_s = np.zeros(self.num_workers)
-        self._assemble_s = 0.0
         self._cursor_shardings = jax.tree.map(
             lambda s: NamedSharding(mesh, s), cursor_pspecs(data_axes=self.data_axes)
         )
@@ -295,44 +289,15 @@ class DistributedPump(SharedCountsScheduler):
                 for src, wins in zip(self._stream_sources, win_lists)
             ]
             try:
-                if self.telemetry is None:
-                    for wds in zip(*streams):
-                        yield self._assemble(wds)
-                else:
-                    # zip with per-worker gather timing: worker w's
-                    # accumulator measures how long ITS next window took
-                    # (the per-worker I/O skew the psum round then has
-                    # to wait out).
-                    while True:
-                        wds = []
-                        for w, st in enumerate(streams):
-                            t0 = time.perf_counter()
-                            try:
-                                wd = next(st)
-                            except StopIteration:
-                                return
-                            self._worker_gather_s[w] += time.perf_counter() - t0
-                            wds.append(wd)
-                        t0 = time.perf_counter()
-                        out = self._assemble(wds)
-                        self._assemble_s += time.perf_counter() - t0
-                        yield out
+                # one fetch span per worker window: the per-worker I/O
+                # skew the psum round then has to wait out
+                for wds in zip(*(spanned(st, "fetch") for st in streams)):
+                    yield self._assemble(wds)
             finally:
                 for st in streams:
                     st.close()
 
         return rounds(), n_rounds
-
-    def _round_batch_extra(self) -> dict:
-        """Per-worker gather + assemble wall accumulated since the last
-        poll (see `SharedCountsScheduler._emit_round_batch`)."""
-        extra = {
-            "worker_gather_s": [float(s) for s in self._worker_gather_s],
-            "assemble_s": float(self._assemble_s),
-        }
-        self._worker_gather_s[:] = 0.0
-        self._assemble_s = 0.0
-        return extra
 
     def _fetch_window(self, win: np.ndarray) -> WindowData:
         """Ad-hoc global window (MatchServer.step / run_window): split

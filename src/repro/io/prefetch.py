@@ -41,6 +41,7 @@ import numpy as np
 
 from repro.io.block_source import BlockSource, WindowData
 from repro.io.faults import FetchCancelled, find_resilient
+from repro.obs.spans import span
 
 __all__ = ["PrefetchSource"]
 
@@ -133,11 +134,10 @@ class PrefetchSource:
                 for win in windows:
                     if stop.is_set():
                         return
-                    if tel is None:
+                    t0 = time.perf_counter()
+                    with span("fetch"):
                         wd = self.inner.fetch(win, pad_to)
-                    else:
-                        t0 = time.perf_counter()
-                        wd = self.inner.fetch(win, pad_to)
+                    if tel is not None:
                         fetch_times.append(time.perf_counter() - t0)
                     if not _put(("data", wd)):
                         return

@@ -3,15 +3,46 @@
 FastMatch's claims are rate claims: tuples drawn per query, rounds to
 retirement, speedup at equal recall (paper Sec 5-6). This package is the
 measurement layer that makes those rates first-class at serve time
-instead of post-hoc benchmark artifacts: a `MetricsRegistry` of
-counters/gauges/latency histograms with Prometheus-text and JSON
-exporters, a `Tracer` recording per-query lifecycle and per-round-batch
-events into a bounded ring with a JSONL sink, and per-query
-tuples-to-confidence trajectories (`Telemetry`). Everything records at
-existing host-sync/poll boundaries — the jitted `fused_round` /
-pump-round path is untouched, and a telemetry-on run is bit-identical
-to a telemetry-off run (tests/test_obs.py; gated <2% round-throughput
-overhead in benchmarks/telemetry_overhead.py).
+instead of post-hoc benchmark artifacts. It has two halves:
+
+* Program spans (`span`), always on: the host work of the serving path,
+  written into the profiler's own trace (`jax.profiler`) on the
+  device's clock, so the device's idle time can be charged to the host
+  work around it. They record only while a profiler runs.
+* Telemetry (``MatchServer(telemetry=True)``): a `MetricsRegistry` of
+  counters/gauges/latency histograms with Prometheus-text and JSON
+  exporters, a `Tracer` recording per-query lifecycle and per-poll
+  round-batch counts into a bounded ring with a JSONL sink, and
+  per-query tuples-to-confidence trajectories (`Telemetry`). It records
+  at existing host-sync/poll boundaries — the jitted `fused_round` /
+  pump-round path is untouched, and a telemetry-on run is bit-identical
+  to a telemetry-off run (tests/test_obs.py; gated <2% round-throughput
+  overhead in benchmarks/telemetry_overhead.py). A scrape bins on the
+  host and dispatches no device work.
+
+Program spans
+=============
+
+Each is ``fastmatch.<name>``; the per-query ones carry ``qid=``:
+
+  fastmatch.admit (qid)  — `SharedCountsScheduler.admit`: the slot
+                           write, the admission stats step and its poll
+  fastmatch.retire (qid) — `SharedCountsScheduler.retire`: the answer's
+                           assembly, the top-k ids to the host, the
+                           slot clear
+  fastmatch.sync         — `SharedCountsScheduler._sync`: the one
+                           batched device_get (waiting for the round
+                           plus every mirror's copy);
+                           ``scheduler.host_sync_bytes`` counts its bytes
+  fastmatch.dispatch     — enqueueing a jitted round or ingest round
+  fastmatch.fetch        — building a window: the ad-hoc fetch, each
+                           window pulled from a pass stream, the
+                           prefetch worker's fetch, each pump worker's
+  fastmatch.pass         — a new pass's order (the read-mask gather over
+                           the visit order)
+  fastmatch.complete     — exact completion
+  fastmatch.collect      — `MatchServer._collect`: outcomes to
+                           MatchResults, the autosave check
 
 Metric ↔ paper-quantity map
 ===========================
@@ -43,8 +74,6 @@ Registry metrics (``MatchServer(telemetry=True)``):
                                      crosses delta)
   fastmatch_query_wall_seconds     — submit→retire latency (the
                                      interactivity budget of Sec 1)
-  fastmatch_round_batch_seconds    — host-side wall per dispatched
-                                     round batch (gather+dispatch+sync)
   prefetch_wait_seconds            — consumer stalls waiting on the
                                      sampling engine: Sec 4.2's "must
                                      never stall the statistics engine",
@@ -77,8 +106,8 @@ Confidence-trajectory columns (`Telemetry.confidence_curve`):
                   could be handed mid-query
 
 Trace events (`Tracer`, JSONL): ``query_enqueue`` → ``query_admit`` →
-``round_batch``* (windows, gather/dispatch/sync wall; pump adds
-per-worker gather + assemble) → ``query_retire`` → ``query_done``
+``round_batch``* (windows since the last poll, rounds, blocks and
+tuples read) → ``query_retire`` → ``query_done``
 (the rid↔qid join, emitted by `MatchServer`) (+ ``pass_start``,
 ``exact_completion``, ``budget_exhausted``, ``checkpoint_save``,
 ``checkpoint_gc``, ``prefetch_stream``, ``prefetch_worker_error``,
@@ -93,6 +122,7 @@ from repro.obs.registry import (
     Histogram,
     MetricsRegistry,
 )
+from repro.obs.spans import SPAN_PREFIX, span, spanned
 from repro.obs.telemetry import CURVE_COLUMNS, Telemetry
 from repro.obs.tracer import TIMING_FIELDS, Tracer
 
@@ -103,7 +133,10 @@ __all__ = [
     "Gauge",
     "Histogram",
     "MetricsRegistry",
+    "SPAN_PREFIX",
     "TIMING_FIELDS",
     "Telemetry",
     "Tracer",
+    "span",
+    "spanned",
 ]

@@ -8,11 +8,8 @@ prefetch worker thread records from off the main thread):
   Gauge     — last-write-wins float
   Histogram — fixed upper-bound bins (Prometheus ``le`` semantics) with
               running sum/count; observations are O(1) appends on the
-              hot path and are BINNED LAZILY at snapshot time through
-              the repo's own histogram kernel (`repro.kernels.ops`) —
-              the same one-hot-contraction op the sampling engine uses
-              for tuple ingest, here counting latency samples into
-              latency bins (V_Z=1, V_X=num_bins)
+              hot path and are BINNED LAZILY at snapshot time on the
+              host (numpy), so a scrape never dispatches device work
 
 `MetricsRegistry` is the factory/namespace: ``registry.counter(name)``
 returns the existing metric or creates it (re-registering under a
@@ -108,8 +105,7 @@ class Histogram:
 
     ``observe`` is an O(1) list append; samples are binned lazily by
     `_flush` — ``np.searchsorted`` assigns each sample its bin index and
-    the repo's histogram kernel counts them (one candidate row, one bin
-    per x-value: exactly the ingest op at V_Z=1). Bin counts are stored
+    ``np.bincount`` counts them, on the host. Bin counts are stored
     NON-cumulative per bin plus an overflow bin; the exporter emits the
     cumulative ``le`` form.
     """
@@ -147,21 +143,16 @@ class Histogram:
             self._count += len(vals)
 
     def _flush(self) -> None:
-        """Bin pending samples through the repo's histogram kernel."""
+        """Bin pending samples on the host."""
         with self._lock:
             pending, self._pending = self._pending, []
         if not pending:
             return
-        from repro.kernels import ops  # deferred: registry core is jax-free
-
-        vals = np.asarray(pending, np.float64)
         # side="left": v == edge lands in that edge's bucket (v <= le).
-        bins = np.searchsorted(self.edges, vals, side="left").astype(np.int32)
-        counts = ops.histogram(
-            np.zeros(len(bins), np.int32), bins, v_z=1, v_x=len(self.edges) + 1
-        )
+        bins = np.searchsorted(self.edges, np.asarray(pending, np.float64), side="left")
+        counts = np.bincount(bins, minlength=len(self.edges) + 1)
         with self._lock:
-            self._counts += np.asarray(counts, np.int64)[0]
+            self._counts += counts
 
     @property
     def count(self) -> int:

@@ -154,7 +154,7 @@ from repro.core.multiquery import (
     cache_config_hash,
 )
 from repro.io import as_block_source, maybe_chaos
-from repro.obs import Telemetry
+from repro.obs import Telemetry, span
 
 __all__ = [
     "AnytimeAnswer",
@@ -504,26 +504,27 @@ class MatchServer:
 
     def _collect(self) -> None:
         """Convert freshly retired scheduler outcomes into MatchResults."""
-        for qid, out in list(self.scheduler.outcomes.items()):
-            rid = self._rid_of_qid.pop(qid, None)
-            if rid is None:
-                continue  # already collected
-            self._qid_of_rid.pop(rid, None)
-            del self.scheduler.outcomes[qid]
-            res = self.results[rid] = self._to_result(rid, out)
-            if out.anytime is not None:
-                out.anytime.result = res
-                self._anytime[rid] = out.anytime
-            self._retired_since_save += 1
-            if self.telemetry is not None:
-                # The rid↔qid join point: query_enqueue events carry the
-                # request id, the scheduler's admit/retire events the
-                # slot-assigned qid — this event links the two.
-                self.telemetry.tracer.emit(
-                    "query_done", rid=rid, qid=qid, exact=res.exact,
-                    tuples=res.tuples_read, wall_s=res.wall_time_s,
-                )
-        self._maybe_autosave()
+        with span("collect"):
+            for qid, out in list(self.scheduler.outcomes.items()):
+                rid = self._rid_of_qid.pop(qid, None)
+                if rid is None:
+                    continue  # already collected
+                self._qid_of_rid.pop(rid, None)
+                del self.scheduler.outcomes[qid]
+                res = self.results[rid] = self._to_result(rid, out)
+                if out.anytime is not None:
+                    out.anytime.result = res
+                    self._anytime[rid] = out.anytime
+                self._retired_since_save += 1
+                if self.telemetry is not None:
+                    # The rid↔qid join point: query_enqueue events carry the
+                    # request id, the scheduler's admit/retire events the
+                    # slot-assigned qid — this event links the two.
+                    self.telemetry.tracer.emit(
+                        "query_done", rid=rid, qid=qid, exact=res.exact,
+                        tuples=res.tuples_read, wall_s=res.wall_time_s,
+                    )
+            self._maybe_autosave()
 
     def _to_result(self, rid: int, out: QueryOutcome) -> MatchResult:
         wall = time.perf_counter() - self._submit_time.pop(rid)
@@ -637,8 +638,9 @@ class MatchServer:
         if not sched.tickets:
             return
         if self._pass_order is None or self._pass_pos >= len(self._pass_order):
-            eligible = ~sched.read_mask[sched.order] & ~sched.quarantined[sched.order]
-            unread = sched.order[eligible]
+            with span("pass"):
+                eligible = ~sched.read_mask[sched.order] & ~sched.quarantined[sched.order]
+                unread = sched.order[eligible]
             # A zero-read pass only proves sampling is exhausted for the
             # queries that were live during it — a query admitted in its
             # final windows gets a fresh pass before the exact fallback.
