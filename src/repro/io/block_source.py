@@ -10,10 +10,21 @@ ingest and the read bookkeeping.
 `fetch` is random access (used by exact completion); `stream` is the
 sequential hot path a pass runs on, and is the hook `PrefetchSource`
 overrides to overlap the next window's gather with the current round.
+
+A device-resident fetch is ONE compiled program (`gather_window`) whose
+device cost scales with the window, not the table. Its bitmap is stored
+with the word axis zero-padded to a multiple of `LANES`: on a TPU a
+gather from a table whose minor dim is not lane-aligned first copies
+the whole table into the layout the gather wants (1.1 GB at TAXI-q1's
+236 words, on every fetch), while the aligned table is read in place.
+The tiled layout already pads the minor dim to 128 lanes, so the
+padding costs no device memory that was not already spent.
 """
 
 from __future__ import annotations
 
+import functools
+import threading
 from typing import Iterable, Iterator, NamedTuple, Optional, Protocol, runtime_checkable
 
 import jax
@@ -23,6 +34,8 @@ import numpy as np
 from repro.data.layout import BlockedDataset
 
 __all__ = ["BlockSource", "InMemorySource", "ShardedSource", "WindowData", "as_block_source"]
+
+LANES = 128  # minor-dim tile of the TPU's default (8, 128) layout
 
 
 class WindowData(NamedTuple):
@@ -39,6 +52,18 @@ class WindowData(NamedTuple):
     x: jax.Array  # (L, B) i32 attribute values, -1 padded
     bitmap: jax.Array  # (L, W) uint32 packed presence bitmap rows
     valid: jax.Array  # (L,) bool — False on window padding rows
+
+
+@functools.partial(jax.jit, static_argnames=("words",))
+def gather_window(z, x, bitmap, idx, valid, *, words: int) -> WindowData:
+    """One padded window gathered from device-resident tables in one
+    program. The tables are arguments, never closed over: a closure
+    would bake them into the program as constants and compile once per
+    source. ``bitmap`` may be lane-padded; the window keeps its first
+    ``words`` words, sliced off whole gathered rows: a gather of the
+    sliced rows (``bitmap[idx, :words]``) lowers to a loop of one
+    dynamic slice per block on a TPU."""
+    return WindowData(idx, z[idx], x[idx], bitmap[idx][:, :words], valid)
 
 
 @runtime_checkable
@@ -62,7 +87,8 @@ class InMemorySource:
     """The whole blocked dataset behind the source protocol.
 
     ``device_resident=True`` (default) keeps the block arrays on device:
-    a fetch is a device-side gather and costs no host traffic. With
+    a fetch is one device-side gather program (`gather_window`; `gathers`
+    counts them) and costs no host traffic beyond its index. With
     ``device_resident=False`` blocks stay in host memory (a stand-in for
     disk or a remote FS) and each fetch gathers on host and transfers
     one window — the case `PrefetchSource` exists to overlap.
@@ -75,10 +101,15 @@ class InMemorySource:
         self.v_x = dataset.v_x
         self.tuples_per_block = (dataset.z_blocks >= 0).sum(axis=1)
         self.device_resident = device_resident
+        self.gathers = 0  # one-program window gathers dispatched
+        self._gathers_lock = threading.Lock()  # prefetch workers fetch beside the caller
+        self._words = dataset.bitmap.shape[1]
         if device_resident:
             self._z = jnp.asarray(dataset.z_blocks)
             self._x = jnp.asarray(dataset.x_blocks)
             self._bitmap = jnp.asarray(dataset.bitmap)
+            if lane_pad := -self._words % LANES:
+                self._bitmap = jnp.pad(self._bitmap, ((0, 0), (0, lane_pad)))
         else:
             self._z = np.asarray(dataset.z_blocks, np.int32)
             self._x = np.asarray(dataset.x_blocks, np.int32)
@@ -98,8 +129,9 @@ class InMemorySource:
     def fetch(self, win: np.ndarray, pad_to: Optional[int] = None) -> WindowData:
         idx, valid = self._pad(win, pad_to)
         if self.device_resident:
-            j = jnp.asarray(idx)
-            return WindowData(j, self._z[j], self._x[j], self._bitmap[j], jnp.asarray(valid))
+            with self._gathers_lock:
+                self.gathers += 1
+            return gather_window(self._z, self._x, self._bitmap, idx, valid, words=self._words)
         # Host-resident: stay numpy — the consumer decides when the one
         # host→device transfer happens (jit dispatch, or the pump's
         # sharded device_put of the assembled multi-worker window).

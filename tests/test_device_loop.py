@@ -18,6 +18,10 @@ windows. This suite pins the refactor to the old semantics:
 Plus contract tests for the new `repro.io` layer itself.
 """
 
+import os
+import sys
+import threading
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -309,6 +313,71 @@ class TestBlockSourceContract:
         host = InMemorySource(blocked, device_resident=False).fetch(np.arange(5), pad_to=6)
         for a, b in zip(dev, host):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.parametrize("v_z,words", [(161, 6), (7548, 236)])
+    def test_lane_padded_fetch_matches_host_resident(self, v_z, words):
+        """The device table's bitmap is lane-padded (6 -> 128, 236 -> 256
+        words); the window it serves is not."""
+        rng = np.random.default_rng(v_z)
+        n = 37 * 64 - 5  # a ragged last block
+        blocked = block_layout(
+            rng.integers(0, v_z, n), rng.integers(0, 24, n), v_z=v_z, v_x=24,
+            block_size=64, seed=v_z,
+        )
+        assert blocked.bitmap.shape[1] == words
+        win = np.array([36, 0, 17, 5, 2])
+        dev = InMemorySource(blocked).fetch(win, pad_to=8)
+        host = InMemorySource(blocked, device_resident=False).fetch(win, pad_to=8)
+        assert dev.bitmap.shape == (8, words)
+        for a, b in zip(dev, host):
+            assert a.shape == b.shape and a.dtype == b.dtype
+            np.testing.assert_array_equal(np.asarray(a), b)
+
+    def test_gathers_counts_device_fetches(self, dataset):
+        _, _, blocked = dataset
+        src = InMemorySource(blocked)
+        assert src.gathers == 0
+        for i, win in enumerate([np.arange(3), np.arange(4, 9), np.array([2])], 1):
+            src.fetch(win, pad_to=8)
+            assert src.gathers == i
+        list(src.stream([np.arange(2), np.arange(2, 4)], pad_to=8))
+        assert src.gathers == 5
+        host = InMemorySource(blocked, device_resident=False)
+        host.fetch(np.arange(3), pad_to=8)
+        assert host.gathers == 0
+
+    def test_gathers_exact_under_concurrent_fetches(self, dataset):
+        """Prefetch workers fetch beside the caller: no lost update."""
+        _, _, blocked = dataset
+        src = InMemorySource(blocked)
+        src.fetch(np.arange(4), pad_to=8)  # compile outside the race
+        per, workers = 50, 2 * (os.cpu_count() or 1) + 2
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            threads = [
+                threading.Thread(
+                    target=lambda: [src.fetch(np.arange(4), pad_to=8) for _ in range(per)]
+                )
+                for _ in range(workers)
+            ]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(t.is_alive() for t in threads)
+        assert src.gathers == 1 + per * workers
+
+    def test_cache_config_hash_ignores_residency(self, dataset):
+        """Warm-restart snapshots hash the probe's W words, not the
+        device table's lane padding."""
+        spec, _, blocked = dataset
+        mspec = mq.MultiQuerySpec(v_z=spec.v_z, v_x=spec.v_x, max_queries=2, k_cap=K)
+        dev = mq.cache_config_hash(InMemorySource(blocked), mspec)
+        host = mq.cache_config_hash(InMemorySource(blocked, device_resident=False), mspec)
+        assert dev == host
 
     def test_sharded_source_speaks_global_ids(self, dataset):
         _, _, blocked = dataset

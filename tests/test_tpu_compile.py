@@ -13,6 +13,8 @@ for a described (not attached) v5e 2x2, at the served shapes:
     V_X = 8192 on the two-sweep lane-tiled path;
   * AnyActive at the packed bitmap widths of V_Z = 161 and 7,548, for
     windows of 1 to 5,000 blocks;
+  * the device-resident source's window gather at the benchmark's table
+    size, which must read the tables in place (no table-sized copy);
   * the whole jitted serving round (`multiquery.fused_round`) at the
     taxi_q1 and flights_q1 shapes, holding all three kernels;
   * the four-chip rounds: the pump on a 4x1 mesh and the single-stream
@@ -23,6 +25,7 @@ so only the worker that runs this file loads the TPU compiler.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +37,7 @@ from repro.core import distributed as dist
 from repro.core import multiquery as mq
 from repro.core.bitmap import words_for
 from repro.io import WindowData
+from repro.io.block_source import LANES, gather_window
 from repro.kernels import autotune, ops
 
 SHAPES = [(161, 24), (7548, 24), (191, 2), (161, 161)]
@@ -117,6 +121,30 @@ def test_anyactive_compiles(one_chip, v_z, num_blocks):
         impl="pallas",
     ).compile()
     assert _kernels(compiled) == {"anyactive"}
+
+
+@pytest.mark.parametrize("v_z", [161, 7548])
+def test_window_gather_compiles(one_chip, v_z):
+    """A 512-block window from a 600M-tuple table. A bitmap whose word
+    axis is not lane-aligned (6 or 236 words) is copied whole into the
+    gather's layout on every fetch; the source's lane-padded one is not.
+    Each table's rows come in one fused gather, not a loop of per-block
+    dynamic slices."""
+    num_blocks, window, w = 1_171_875, 512, words_for(v_z)
+    table = _sds((num_blocks, BLOCK), jnp.int32, one_chip)
+    compiled = gather_window.lower(
+        table, table, _sds((num_blocks, -(-w // LANES) * LANES), jnp.uint32, one_chip),
+        _sds((window,), jnp.int32, one_chip), _sds((window,), jnp.bool_, one_chip), words=w,
+    ).compile()
+    hlo = compiled.as_text()
+    table_copies = [
+        line for line in hlo.splitlines()
+        if re.search(r"\bcopy(-start|-done)?\(", line) and f"[{num_blocks}," in line
+    ]
+    assert not table_copies
+    assert not re.search(r"\bwhile\(", hlo)
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+    assert compiled.out_info.bitmap.shape == (window, w)
 
 
 def _round_args(spec, num_blocks, lookahead, state_sharding, rep):
