@@ -2,11 +2,19 @@
 
 The distributions follow the paper's query shapes: a target
 distribution Q over V_X, ``n_close`` candidates planted at controlled l1
-distances from Q (the most frequent ones in the Zipf order), every
-other candidate drawn from a Dirichlet prior into a band at least
-``far_distance`` from Q, and Zipf-skewed candidate frequencies. It is a
-copy of the construction in the program's ``data/synth.py``, vectorised, so
-that the yardstick does not move when the program does.
+distances from Q, every other candidate drawn from a Dirichlet prior
+into a band at least ``far_distance`` from Q, and Zipf-skewed candidate
+frequencies. Two keys of the configuration choose the query's shape:
+
+  close_rank   "head" (default): the close set is the ``n_close`` most
+               frequent candidates; "tail": the ``n_close`` least
+               frequent, the paper's rare top-k queries (FLIGHTS-q2/q3)
+  target_kind  "peaked" (default): Q drawn from Dirichlet(2);
+               "uniform": Q = 1/V_X everywhere, drawing nothing (FLIGHTS-q4)
+
+It is a copy of the construction in the program's ``data/synth.py``,
+vectorised, and imports nothing of the program, so that the yardstick
+does not move when the program does.
 
 Tuples are drawn i.i.d. from that distribution straight into blocks of
 `BLOCK` tuples: each draws its joint key z * V_X + x from Walker's alias
@@ -43,16 +51,31 @@ def perturb_distribution(p: np.ndarray, dist: float, rng: np.random.Generator) -
     return v / v.sum()
 
 
+CLOSE_RANKS = ("head", "tail")
+TARGET_KINDS = ("peaked", "uniform")
+
+
+def _choice(cfg: dict, key: str, allowed: tuple) -> str:
+    value = cfg.get(key, allowed[0])
+    if value not in allowed:
+        raise ValueError(f"{key} must be one of {allowed}, got {value!r}")
+    return value
+
+
 def distributions(cfg: dict, rng: np.random.Generator) -> dict:
     """Target, candidate frequencies and per-candidate distributions."""
     v_z, v_x = cfg["v_z"], cfg["v_x"]
-    q = rng.dirichlet(np.full(v_x, 2.0))
-    q = q / q.sum()
+    close_rank = _choice(cfg, "close_rank", CLOSE_RANKS)
+    if _choice(cfg, "target_kind", TARGET_KINDS) == "uniform":
+        q = np.full(v_x, 1.0 / v_x)
+    else:
+        q = rng.dirichlet(np.full(v_x, 2.0))
+        q = q / q.sum()
     freq = np.arange(1, v_z + 1, dtype=np.float64) ** (-cfg["zipf_a"])
     freq /= freq.sum()
     n_close = cfg["n_close"]
     ids = np.arange(v_z)
-    close = ids[:n_close]
+    close = ids[v_z - n_close:] if close_rank == "tail" else ids[:n_close]
     hists = np.zeros((v_z, v_x))
     spread = np.linspace(0.5, 1.5, num=max(n_close, 1))
     for i, z in enumerate(close):

@@ -1,9 +1,11 @@
 """The benchmark's device generator and its exact counts, at small sizes."""
 
+import hashlib
+
 import numpy as np
 import pytest
 
-from chipbench import gen
+from chipbench import gen, harness, spec
 from repro.core.bitmap import build_block_bitmap
 
 CFG = dict(v_z=300, v_x=24, n_close=10, close_distance=0.05, far_distance=0.45,
@@ -25,6 +27,84 @@ def test_distributions_plant_the_close_set():
     assert d[close].max() < 0.1
     assert np.delete(d, close).min() >= CFG["far_distance"]
     assert np.isclose(dist["freq"].sum(), 1.0) and np.all(np.diff(dist["freq"]) <= 0)
+
+
+@pytest.mark.parametrize("target_kind", ["peaked", "uniform"])
+def test_tail_close_rank_plants_the_least_frequent(target_kind):
+    cfg = dict(CFG, close_rank="tail", target_kind=target_kind, zipf_a=1.2)
+    dist = gen.distributions(cfg, np.random.default_rng(0))
+    d = np.abs(dist["hists"] - dist["target"]).sum(axis=1)
+    close = dist["close_ids"]
+    assert np.array_equal(close, np.arange(CFG["v_z"] - 10, CFG["v_z"]))
+    assert np.all(dist["freq"][close] < dist["freq"][: CFG["v_z"] - 10].min())
+    assert d[close].max() < 0.1
+    assert d[: CFG["v_z"] - 10].min() >= CFG["far_distance"]
+
+
+def test_uniform_target_is_exact_and_draws_nothing():
+    cfg = dict(CFG, target_kind="uniform")
+    dist = gen.distributions(cfg, np.random.default_rng(6))
+    assert np.array_equal(dist["target"], np.full(CFG["v_x"], 1.0 / CFG["v_x"]))
+    # the first draw of the stream goes to the first close candidate
+    first = gen.perturb_distribution(
+        dist["target"], CFG["close_distance"] * 0.5, np.random.default_rng(6)
+    )
+    assert np.array_equal(dist["hists"][0], first)
+
+
+@pytest.mark.parametrize("key,value", [("close_rank", "middle"), ("close_rank", None),
+                                       ("target_kind", "Uniform"), ("target_kind", "zipf")])
+def test_unknown_shape_is_refused(key, value):
+    with pytest.raises(ValueError, match=key):
+        gen.distributions(dict(CFG, **{key: value}), np.random.default_rng(0))
+
+
+# sha256 prefixes of what the default shape (head, peaked) makes from a seed,
+# pinned when the shape keys were added: a change to how the default path
+# draws from its random streams moves the cells' tables and fails here
+PIN_SEED = 4_500_000_001
+PINS = {
+    "small.0": "d5b7ba3f8d3ef0fc",
+    "small.7": "ef4c4622a63b2c93",
+    "taxi_q1.dist": "a758adbb6570c75f",
+    "taxi_q1.table": "75e62400fc7aec64",
+    "taxi_q1.targets": "f78d5719ca37dcdc",
+    "flights_q1.dist": "eecba41539c1307a",
+    "flights_q1.table": "09ff6712fd0ded3b",
+}
+
+
+def _digest(*arrays) -> str:
+    h = hashlib.sha256()
+    for a in arrays:
+        a = np.ascontiguousarray(np.asarray(a))
+        h.update(f"{a.dtype.str}{a.shape}".encode())
+        h.update(a.tobytes())
+    return h.hexdigest()[:16]
+
+
+def _pinned(name: str) -> str:
+    if name.startswith("small."):
+        dist = gen.distributions(CFG, np.random.default_rng(int(name.split(".")[1])))
+        return _digest(dist["target"], dist["freq"], dist["hists"], dist["close_ids"])
+    config, part = name.split(".")
+    cfg = spec.load_json(spec.HERE / "configs" / f"{config}.json")
+    words = harness.seed_words(PIN_SEED)
+    dist = gen.distributions(cfg, np.random.default_rng(words[:2]))
+    if part == "dist":
+        return _digest(dist["target"], dist["freq"], dist["hists"], dist["close_ids"])
+    if part == "table":
+        return _digest(*gen.make_table(dist, 512 * 64, words[:2]))
+    # per-query targets around the planted one, drawn as `load.Client.submit` draws them
+    lo, hi = spec.load_json(spec.HERE / "traffic" / "cold8.json")["target_l1"]
+    rng = np.random.default_rng(words[2:])
+    return _digest(*(gen.perturb_distribution(dist["target"], float(rng.uniform(lo, hi)), rng)
+                     for _ in range(8)))
+
+
+@pytest.mark.parametrize("name", sorted(PINS))
+def test_default_shape_is_pinned(name):
+    assert _pinned(name) == PINS[name]
 
 
 def test_table_shapes_and_ranges(table):
