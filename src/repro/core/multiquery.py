@@ -1008,7 +1008,8 @@ class SharedCountsScheduler:
         self.telemetry = telemetry
         if telemetry is not None:
             reg = telemetry.registry
-            self._tel_last = {"rounds": 0, "blocks": 0, "tuples": 0, "passes": 0}
+            self._tel_last = {"rounds": 0, "blocks": 0, "considered": 0,
+                              "tuples": 0, "passes": 0}
             # Poll-time recording is two appends (see `_record_poll`);
             # everything dict/registry-shaped happens in
             # `flush_telemetry`, batched, at lifecycle boundaries or on
@@ -1016,7 +1017,7 @@ class SharedCountsScheduler:
             # after a device phase and costs ~10x its warm price.
             self._poll_buf: list = []
             self._tel_pending = {"syncs": 0, "rounds": 0, "blocks": 0,
-                                 "tuples": 0, "passes": 0}
+                                 "considered": 0, "tuples": 0, "passes": 0}
             telemetry.add_flush_hook(self.flush_telemetry)
             self._c_syncs = reg.counter(
                 "fastmatch_host_syncs_total", "device-host polls performed")
@@ -1024,6 +1025,9 @@ class SharedCountsScheduler:
                 "fastmatch_rounds_total", "windows dispatched (stats iterations)")
             self._c_blocks = reg.counter(
                 "fastmatch_blocks_read_total", "blocks ingested into shared counts")
+            self._c_considered = reg.counter(
+                "fastmatch_blocks_considered_total",
+                "valid blocks in dispatched windows, read or skipped")
             self._c_tuples = reg.counter(
                 "fastmatch_tuples_read_total", "tuples drawn (m of Theorem 1)")
             self._c_passes = reg.counter(
@@ -1202,9 +1206,11 @@ class SharedCountsScheduler:
         p["syncs"] += 1
         p["rounds"] += self.rounds - last["rounds"]
         p["blocks"] += self.blocks_read - last["blocks"]
+        p["considered"] += self.blocks_considered - last["considered"]
         p["tuples"] += self.tuples_read - last["tuples"]
         p["passes"] += self.passes - last["passes"]
         last.update(rounds=self.rounds, blocks=self.blocks_read,
+                    considered=self.blocks_considered,
                     tuples=self.tuples_read, passes=self.passes)
         if self.tickets:
             # The entry carries its own snapshot of the live ticket set
@@ -1238,6 +1244,7 @@ class SharedCountsScheduler:
             self._c_syncs.inc(p["syncs"])
             self._c_rounds.inc(p["rounds"])
             self._c_blocks.inc(p["blocks"])
+            self._c_considered.inc(p["considered"])
             self._c_tuples.inc(p["tuples"])
             self._c_passes.inc(p["passes"])
             for key in p:
@@ -1276,7 +1283,8 @@ class SharedCountsScheduler:
         if self.telemetry is not None:
             self.telemetry.tracer.emit(
                 "round_batch", windows=windows, rounds=self.rounds,
-                blocks_read=self.blocks_read, tuples_read=self.tuples_read,
+                blocks_read=self.blocks_read, blocks_considered=self.blocks_considered,
+                tuples_read=self.tuples_read,
             )
 
     # -- warm-start persistence --------------------------------------------

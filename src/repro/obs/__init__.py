@@ -55,6 +55,12 @@ Registry metrics (``MatchServer(telemetry=True)``):
   fastmatch_blocks_read_total      — block-granular reads of the Sec 4.2
                                      bitmap-driven I/O manager (the unit
                                      AnyActive decides on)
+  fastmatch_blocks_considered_total — valid blocks of the windows
+                                     dispatched, read or skipped:
+                                     AnyActive's denominator, so
+                                     1 - read / considered is its skip
+                                     share (Sec 4.2); read alone cannot
+                                     tell a skip from an unread window
   fastmatch_rounds_total           — statistics-engine iterations /
                                      windows dispatched: the x-axis of
                                      Fig. 5's per-round view of HistSim
@@ -106,8 +112,8 @@ Confidence-trajectory columns (`Telemetry.confidence_curve`):
                   could be handed mid-query
 
 Trace events (`Tracer`, JSONL): ``query_enqueue`` → ``query_admit`` →
-``round_batch``* (windows since the last poll, rounds, blocks and
-tuples read) → ``query_retire`` → ``query_done``
+``round_batch``* (windows since the last poll, rounds, blocks read
+and considered, tuples read) → ``query_retire`` → ``query_done``
 (the rid↔qid join, emitted by `MatchServer`) (+ ``pass_start``,
 ``exact_completion``, ``budget_exhausted``, ``checkpoint_save``,
 ``checkpoint_gc``, ``prefetch_stream``, ``prefetch_worker_error``,

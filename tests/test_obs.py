@@ -364,6 +364,31 @@ class TestServerTelemetry:
         assert reg.get("fastmatch_host_syncs_total").value == sched.host_syncs
         assert reg.get("fastmatch_queries_retired_total").value == len(res)
 
+    @pytest.mark.parametrize("policy", ["anyactive", "scan"])
+    def test_blocks_considered_counter(self, dataset, targets, policy):
+        """AnyActive's denominator is exported beside its reads: every
+        valid block of a dispatched window is considered, read or not, so
+        considered >= read, with equality when Scan reads every block it
+        is handed (each block once, within the first pass)."""
+        _, _, blocked = dataset
+        srv = MatchServer(blocked, max_queries=2, lookahead=64, poll_every=2, seed=11,
+                          telemetry=True, policy=policy)
+        for t in targets:
+            srv.submit(t, k=K, eps=EPS, delta=DELTA)
+        srv.run_until_idle()
+        sched, reg = srv.scheduler, srv.telemetry.registry
+        considered = reg.get("fastmatch_blocks_considered_total").value
+        read = reg.get("fastmatch_blocks_read_total").value
+        assert considered == sched.blocks_considered > 0
+        assert read == sched.blocks_read > 0
+        if policy == "scan":
+            assert considered == read
+        else:
+            assert considered >= read
+        batches = srv.telemetry.tracer.skeleton("round_batch")
+        assert batches and all("blocks_considered" in e for e in batches)
+        assert batches[-1]["blocks_considered"] == sched.blocks_considered
+
     def test_trace_and_prometheus_exports(self, dataset, targets, tmp_path):
         _, _, blocked = dataset
         srv, _, _ = _drain(blocked, targets, telemetry=True)

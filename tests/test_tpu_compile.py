@@ -16,7 +16,8 @@ for a described (not attached) v5e 2x2, at the served shapes:
   * the device-resident source's window gather at the benchmark's table
     size, which must read the tables in place (no table-sized copy);
   * the whole jitted serving round (`multiquery.fused_round`) at the
-    taxi_q1 and flights_q1 shapes, holding all three kernels;
+    taxi_q1 and flights_q1 shapes, and at flights_q2's read mask over 600M
+    tuples, holding all three kernels;
   * the four-chip rounds: the pump on a 4x1 mesh and the single-stream
     candidate-sharded mesh on 1x4.
 
@@ -166,10 +167,12 @@ def _round_args(spec, num_blocks, lookahead, state_sharding, rep):
     return state, cursor, wd
 
 
-@pytest.mark.parametrize("v_z,v_x,num_blocks", [(7548, 24, 62_500), (161, 24, 11_719)])
+@pytest.mark.parametrize("v_z,v_x,num_blocks",
+                         [(7548, 24, 62_500), (161, 24, 11_719), (161, 24, 1_171_875)])
 def test_fused_round_compiles(one_chip, pallas_engine, v_z, v_x, num_blocks):
     """The whole served round (taxi_q1 / flights_q1 shapes, max_queries=8,
-    lookahead=512) compiles with all three kernels in it."""
+    lookahead=512) compiles with all three kernels in it; the last case is a
+    flights_q2 cold session's round, its read mask over 600M tuples."""
     spec = mq.MultiQuerySpec(v_z=v_z, v_x=v_x, max_queries=8, k_cap=10)
     state_sharding = jax.tree.map(lambda _: one_chip, mq.init_multi_state(spec))
     args = _round_args(spec, num_blocks, 512, state_sharding, one_chip)
