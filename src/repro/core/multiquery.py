@@ -95,6 +95,7 @@ from repro.core.policies import mark_window
 from repro.io import BlockSource, WindowData, as_block_source
 from repro.io.faults import WindowQuarantined, find_resilient
 from repro.kernels import autotune, ops
+from repro.kernels.histogram import ingest_lane_fill
 from repro.obs.spans import span, spanned
 from repro.obs.telemetry import Telemetry
 
@@ -1047,6 +1048,10 @@ class SharedCountsScheduler:
                 help="rounds to retirement (paper Fig. 5)")
             self._h_q_wall = reg.histogram(
                 "fastmatch_query_wall_seconds", help="admit-to-retire wall time")
+            reg.gauge(
+                "fastmatch_ingest_lane_fill",
+                "share of the ingest contraction's output cells that are real counts",
+            ).set(ingest_lane_fill(spec.v_z, spec.v_x, self.plans.ingest.z_tile))
 
     # -- device placement hooks (overridden by the data-parallel pump) -----
 

@@ -24,9 +24,11 @@ looked up per shape key, and plans come from measurement:
               so results stay exact (an in-range uint16 round-trip of an
               integer-valued f32 is the identity).
   ingest — per ``(backend, V_Z, V_X, dtype)``: fused histogram+rowsums
-           (one pass, rows reduced from the VMEM-resident block) vs the
-           two-step form (histogram, then a separate row reduction),
-           plus the histogram kernel's s_tile / z_tile.
+           vs the two-step form (histogram, then a separate row
+           reduction) — on the Pallas engine both reduce the rows from
+           the reshaped counts, so they run the same kernel — plus the
+           histogram kernel's s_tile / z_tile (z_tile tiles the packed
+           rows of the flat z * V_X + x key).
 
 Every candidate is bit-identical to the pre-autotune kernels on
 integer-valued counts (enforced by tests/test_autotune.py, which sweeps
@@ -160,7 +162,7 @@ class IngestPlan:
 
     fused: bool = True  # one fused pass vs histogram + separate reduction
     s_tile: int = 2048  # Pallas sample tile, whole (8, 128) int32 tiles
-    z_tile: int = 256  # Pallas candidate-row tile
+    z_tile: int = 256  # Pallas tile of packed key rows (128 z * V_X + x keys a row)
 
     def validate(self) -> None:
         if self.s_tile <= 0 or self.s_tile % SAMPLE_TILE_MULTIPLE:
@@ -331,10 +333,11 @@ def run_ingest(
 ) -> Tuple[jax.Array, jax.Array]:
     """Dispatch one ((V_Z, V_X), (V_Z,)) histogram + row-sums pass.
 
-    fused=True is the one-pass kernel (rows reduced from the resident
-    counts block); fused=False is the PR-2 two-step (histogram, then a
-    separate row reduction). Both are exact on integer counts, so the
-    plan is free to pick the measured-fastest form.
+    fused=True is the one-pass form; fused=False is the two-step
+    (histogram, then a separate row reduction). On the Pallas engine both
+    run one kernel and reduce the rows from its reshaped counts. Both are
+    exact on integer counts, so the plan is free to pick the
+    measured-fastest form.
     """
     plan.validate()
     if engine == "pallas":

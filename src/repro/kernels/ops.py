@@ -51,12 +51,14 @@ tests/test_autotune.py and tests/test_metrics.py):
                           (uint16 counts traffic behind a runtime
                           overflow gate, exact by construction and
                           metric-agnostic — kernels upcast per tile).
-  histogram_with_rowsums  fused: one pass with rows reduced from the
-                          VMEM-resident counts block
+  histogram_with_rowsums  fused: one pass
                           (`histogram_with_rowsums_pallas` /
                           `histogram_with_rowsums_ref`) vs the two-step
-                          histogram + separate row reduction;
-                          s_tile / z_tile (Pallas tiling).
+                          histogram + separate row reduction (on the
+                          Pallas engine the same kernel, rows reduced
+                          from its reshaped counts either way);
+                          s_tile / z_tile (Pallas tiling: samples, and
+                          packed rows of the flat z * V_X + x key).
                           ``impl="matmul"`` (chunked one-hot
                           contraction) bypasses the plan — it is an
                           explicit engine request, not a tuned variant.

@@ -68,6 +68,12 @@ Registry metrics (``MatchServer(telemetry=True)``):
                                      cost the Sec 4.2 relaxation (and
                                      poll_every) amortizes
   fastmatch_passes_total           — cyclic passes over the block layout
+  fastmatch_ingest_lane_fill       — gauge, set once from the shape:
+                                     V_Z·V_X over the output cells the
+                                     ingest kernel's one-hot contraction
+                                     computes (`kernels.histogram.
+                                     ingest_lane_fill`), the share of
+                                     its MXU work that is real counts
   fastmatch_queries_submitted_total/_admitted_total/_retired_total
                                    — the query population the serving
                                      layer multiplexes onto one stream

@@ -9,7 +9,11 @@ from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ref
 from repro.kernels.anyactive import anyactive_pallas
-from repro.kernels.histogram import histogram_pallas
+from repro.kernels.histogram import (
+    histogram_pallas,
+    histogram_with_rowsums_pallas,
+    ingest_lane_fill,
+)
 from repro.kernels.l1_distance import l1_distance_pallas
 from repro.kernels import ops
 
@@ -58,6 +62,64 @@ class TestHistogramKernel:
         x = jnp.asarray([0, 1, 0, 0], jnp.int32)
         got = histogram_pallas(z, x, v_z=4, v_x=2, interpret=True)
         assert float(got.sum()) == 1.0  # only (0, 0) is in range
+
+
+def _aliasing_ids(v_z, v_x):
+    """Out-of-range pairs whose flat key z * V_X + x would land on a real
+    cell: x == V_X (the next candidate's bin 0), x == -1 (the previous
+    candidate's last bin), z == V_Z or z < 0 with a valid x."""
+    zs = np.arange(v_z, dtype=np.int32)
+    xs = np.arange(v_x, dtype=np.int32) % v_x
+    z = np.concatenate([zs, zs, np.full(v_x, v_z), np.full(v_x, -1)])
+    x = np.concatenate([np.full(v_z, v_x), np.full(v_z, -1), xs, xs])
+    return z.astype(np.int32), x.astype(np.int32)
+
+
+# (v_z, v_x, n, z_tile, out_of_range): the served shapes, V_X at and past
+# the lane width, a candidate straddling two packed rows (37 * 43 = 1591
+# keys, 12.4 rows), several packed-row tiles, a window shorter than one
+# 128-sample row, and out-of-range ids that would alias onto real cells.
+PACKED_CASES = [
+    (7548, 24, 3_000, 256, False),
+    (161, 24, 5_000, 256, False),
+    (191, 2, 4_000, 256, False),
+    (161, 161, 5_000, 256, False),
+    (40, 128, 3_000, 256, False),
+    (37, 43, 4_000, 256, False),
+    (200, 30, 5_000, 16, False),
+    (161, 24, 50, 256, False),
+    (37, 43, 3_000, 8, True),
+]
+
+
+class TestPackedHistogram:
+    @pytest.mark.parametrize("v_z,v_x,n,z_tile,out_of_range", PACKED_CASES)
+    def test_counts_and_rows_bit_exact(self, v_z, v_x, n, z_tile, out_of_range, rng):
+        z = rng.integers(0, v_z, size=n).astype(np.int32)
+        x = rng.integers(0, v_x, size=n).astype(np.int32)
+        if out_of_range:
+            bad_z, bad_x = _aliasing_ids(v_z, v_x)
+            z, x = np.concatenate([z, bad_z]), np.concatenate([x, bad_x])
+        got_c, got_r = histogram_with_rowsums_pallas(
+            jnp.asarray(z), jnp.asarray(x), v_z=v_z, v_x=v_x,
+            s_tile=1024, z_tile=z_tile, interpret=True,
+        )
+        want_c, want_r = ref.histogram_with_rowsums_ref(
+            jnp.asarray(z), jnp.asarray(x), v_z=v_z, v_x=v_x
+        )
+        np.testing.assert_array_equal(np.asarray(got_c), np.asarray(want_c))
+        np.testing.assert_array_equal(np.asarray(got_r), np.asarray(want_r))
+        assert float(got_c.sum()) == n  # every in-range pair, and no other
+
+    @pytest.mark.parametrize(
+        "v_z,v_x,rows_pad,fill", [(7548, 24, 1536, 0.921), (161, 24, 32, 0.943)]
+    )
+    def test_lane_fill(self, v_z, v_x, rows_pad, fill):
+        """Taxi: 1,416 packed rows in 6 tiles of 256, against 0.184 for a
+        z-by-x contraction (7,680 x 128 cells); flights: 31 rows in 32."""
+        got = ingest_lane_fill(v_z, v_x)
+        assert got == v_z * v_x / (rows_pad * 128)
+        assert got == pytest.approx(fill, abs=1e-3)
 
 
 class TestL1DistanceKernel:

@@ -18,6 +18,7 @@ import pytest
 from repro.core.bounds import theorem1_epsilon
 from repro.data.layout import block_layout
 from repro.data.synth import SynthSpec, make_dataset, perturb_distribution
+from repro.kernels.histogram import ingest_lane_fill
 from repro.obs import (
     CURVE_COLUMNS,
     TIMING_FIELDS,
@@ -388,6 +389,15 @@ class TestServerTelemetry:
         batches = srv.telemetry.tracer.skeleton("round_batch")
         assert batches and all("blocks_considered" in e for e in batches)
         assert batches[-1]["blocks_considered"] == sched.blocks_considered
+
+    def test_ingest_lane_fill_gauge(self, dataset, targets):
+        """Set once from the spec and the ingest plan's row tile: 32 x 16
+        keys fill 4 packed rows, padded to one (8, 128) tile."""
+        _, _, blocked = dataset
+        srv = MatchServer(blocked, max_queries=2, lookahead=64, telemetry=True)
+        gauge = srv.telemetry.registry.get("fastmatch_ingest_lane_fill")
+        assert gauge.value == ingest_lane_fill(32, 16, srv.scheduler.plans.ingest.z_tile)
+        assert gauge.value == 32 * 16 / (8 * 128)
 
     def test_trace_and_prometheus_exports(self, dataset, targets, tmp_path):
         _, _, blocked = dataset

@@ -8,7 +8,8 @@ partitioned program. These tests compile — without running anything —
 for a described (not attached) v5e 2x2, at the served shapes:
 
   * the ingest histogram at the served window (512 blocks x 512 tuples)
-    and a small one, for the benchmark's (V_Z, V_X) shapes;
+    and a small one, for the benchmark's (V_Z, V_X) shapes, as exactly
+    one kernel call;
   * the Q-batched distance at Q in {1, 8} for the same shapes, plus
     V_X = 8192 on the two-sweep lane-tiled path;
   * AnyActive at the packed bitmap widths of V_Z = 161 and 7,548, for
@@ -100,6 +101,9 @@ def test_histogram_with_rowsums_compiles(one_chip, v_z, v_x, n):
         ids, ids, v_z=v_z, v_x=v_x, impl="pallas", plan="default"
     ).compile()
     assert _kernels(compiled) == {"histogram_with_rowsums"}
+    # One call per ingest, which the ingest roofline's byte count assumes;
+    # a set of names cannot tell one call from two.
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
 
 @pytest.mark.parametrize("q", [1, 8])
