@@ -87,14 +87,6 @@ GATES = {
         Gate("tau_bytes_reduction_q8", "min", 0.10),       # analytic byte model
         Gate("batched_bytes_growth_q1_to_q8", "max", 0.10),
         Gate("batched_bit_identical", "exact"),
-        # Tuned-dispatch determinism: with the SAME committed plan file,
-        # the chosen variant per Q, the tuned analytic bytes, and the
-        # tuned-arm bit-identity are exact — only the tuned wall-clock
-        # (reported, not gated) may move between runners.
-        Gate("tuned_bit_identical", "exact"),
-        Gate("tuned_variants", "exact"),
-        Gate("ingest_winner", "exact"),
-        Gate("tuned_tau_bytes_reduction_q8", "min", 0.10),
         Gate("ok", "exact"),
     ]),
     "restart": ("BENCH_restart.json", [
@@ -130,15 +122,6 @@ GATES = {
         Gate("prune_sound_hellinger", "exact"),
         Gate("recall_chi2_native", "min", 0.15),
         Gate("recall_hellinger_native", "min", 0.15),
-        Gate("ok", "exact"),
-    ]),
-    # Tuner winners are timing-dependent (never gated); the persistence
-    # contracts and the tuned key counts are deterministic.
-    "autotune": ("BENCH_autotune.json", [
-        Gate("n_tau_keys", "exact"),
-        Gate("n_ingest_keys", "exact"),
-        Gate("roundtrip_byte_stable", "exact"),
-        Gate("stale_schema_fallback", "exact"),
         Gate("ok", "exact"),
     ]),
     # Fault tolerance: the equivalence/recovery booleans and the seeded

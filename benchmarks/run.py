@@ -15,7 +15,6 @@ import time
 
 from benchmarks import (
     anytime_curve,
-    autotune_smoke,
     fault_recovery,
     fig4_bound_ratio,
     fig7_8_epsilon,
@@ -46,7 +45,6 @@ SUITES = {
     "pump": pump_throughput.run,
     "telemetry": telemetry_overhead.run,
     "anytime": anytime_curve.run,
-    "autotune": autotune_smoke.run,
     "faults": fault_recovery.run,
     "metrics": metrics_matrix.run,
 }

@@ -1,4 +1,4 @@
-"""Statistics-engine throughput: Q-batched tau vs unrolled vs the TUNED plan.
+"""Statistics-engine throughput: Q-batched tau vs unrolled.
 
 The multi-query statistics iteration is tau for every live slot. PR-2
 unrolled one `ops.l1_distance` call per slot — Q HBM passes over the
@@ -6,51 +6,34 @@ shared (V_Z, V_X) counts matrix per round. The Q-batched
 `ops.l1_distance_multi` streams the counts once for all slots, so the
 tau bytes moved per round are independent of Q — but bytes are not wall
 time (the committed history shows batched LOSING wall-clock on XLA:CPU
-at Q>=4), which is why the serving loop now dispatches through
-`repro.kernels.autotune` plans. This benchmark measures all three arms
-for Q in {1, 2, 4, 8}:
+at Q>=4). This benchmark measures both arms for Q in {1, 2, 4, 8}:
 
   * tau HBM bytes/round — the roofline bytes-moved model of each path
     (f32; unrolled: Q * (V_Z*V_X + V_X + V_Z); batched:
-    sweeps * V_Z*V_X + Q * (V_X + V_Z); tuned: whatever the committed
-    plan selects, via `autotune.tau_bytes` — uint16 counts halve the
-    counts term). The statistics engine is memory-bound, so bytes
-    moved IS the roofline-projected round time on TPU.
+    sweeps * V_Z*V_X + Q * (V_X + V_Z)). The statistics engine is
+    memory-bound, so bytes moved IS the roofline-projected round time
+    on TPU.
   * rounds/sec — measured wall-clock of the jitted stats step on this
-    host for the unrolled and batched arms, PLUS the ``tau_tuned`` arm:
-    the variant the COMMITTED plan file dispatches for this exact
-    (backend, V_Z, V_X, Q) key — i.e. what `multiquery.stats_step`
-    actually runs in production. When the plan selects the unrolled
-    variant the tuned arm is the same arithmetic program as the
-    reference arm, so its speedup is 1.0 by construction
-    (``same_program`` marks these rows; ``us_tuned`` still reports the
-    independent measurement).
+    host for the unrolled and batched arms.
 
-Plus the ingest row-sum delta, now plan-dispatched: fused
-`ops.histogram_with_rowsums` vs the PR-2 two-step (histogram + separate
-reduction) vs the tuned plan's choice — ``ingest.winner`` records which
-form the committed plan runs (the fix for the fused-753us-vs-two-step-
-716us regression this file used to document).
+Plus the ingest row-sum delta: `ops.histogram_with_rowsums` (one pass)
+vs the two-step form (histogram + separate reduction).
 
 Reported rows (benchmarks/run.py CSV schema):
 
   stats_tau_q{Q}_unrolled  — us per stats round, derived = MB moved
   stats_tau_q{Q}_batched   — us per stats round, derived = MB moved
-  stats_tau_q{Q}_tuned     — us per stats round, derived = MB moved
   stats_tau_bytes_q8       — derived = unrolled/batched bytes ratio (>=4 = pass)
   stats_tau_speedup_q8     — derived = measured unrolled/batched wall ratio
   stats_ingest_fused       — us per fused ingest, derived = MB saved/round
-  stats_ingest_tuned       — us per tuned ingest, derived = 1.0 if winner=fused
 
 Machine-readable results land in benchmarks/results/BENCH_stats.json
 (config stamped with backend/device/jax via `common.env_stamp` so
-`check_regression.py` can refuse cross-hardware comparisons). The
-regression-gated tuned keys are DETERMINISTIC given the committed plan
-file: the chosen variant per Q and the analytic tuned bytes — never the
-tuned wall-clock, which shared runners cannot reproduce.
+`check_regression.py` can refuse cross-hardware comparisons). Wall-clock
+is reported, never gated: shared runners cannot reproduce it.
 
 Set STATS_BENCH_SMOKE=1 for the tiny CI configuration (same code path;
-exits non-zero if any tau arm is not bit-identical to the unrolled
+exits non-zero if the batched arm is not bit-identical to the unrolled
 reference on the production engine or the q=8 bytes reduction drops
 below 4x).
 """
@@ -67,15 +50,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import env_stamp
-from repro.kernels import autotune, ops
+from repro.kernels import ops
 from repro.kernels.l1_distance_multi import _X_TILE as _X_BLOCK  # single-sweep lane bound
 
 SMOKE = bool(int(os.environ.get("STATS_BENCH_SMOKE", "0")))
 QS = (1, 2, 4, 8)
 V_Z, V_X = (256, 256) if SMOKE else (4096, 1024)
 N_SAMPLES = 4_096 if SMOKE else 65_536
-# smoke kernels are microseconds — reps are nearly free, and the tuned
-# arm's measured speedup needs the same noise floor the tuner had
+# smoke kernels are microseconds — reps are nearly free
 REPS = 25 if SMOKE else 10
 
 RESULTS = pathlib.Path(__file__).parent / "results"
@@ -91,14 +73,7 @@ def _tau_unrolled(counts, q_hat):
 
 @jax.jit
 def _tau_batched(counts, q_hat):
-    return ops.l1_distance_multi(counts, q_hat, plan="default")
-
-
-def _tau_tuned_fn(plan):
-    @jax.jit
-    def fn(counts, q_hat):
-        return ops.l1_distance_multi(counts, q_hat, plan=plan)
-    return fn
+    return ops.l1_distance_multi(counts, q_hat)
 
 
 def _time(fn, *args) -> float:
@@ -127,53 +102,29 @@ def run(rows: list) -> None:
     z = jnp.asarray(rng.integers(-1, V_Z, size=N_SAMPLES).astype(np.int32))
     x = jnp.asarray(rng.integers(-1, V_X, size=N_SAMPLES).astype(np.int32))
 
-    registry = autotune.registry()
-    plan_file = registry.path if registry.path and registry.path.exists() else None
-
-    tau_rows, identical, tuned_identical = [], True, True
-    tuned_variants = {}
+    tau_rows, identical = [], True
     for q in QS:
         q_hat = jnp.asarray(
             np.stack([rng.dirichlet(np.ones(V_X)).astype(np.float32) for _ in range(q)])
         )
-        plan = registry.tau_plan(V_Z, V_X, q)
-        tuned_from_file = autotune.tau_key(V_Z, V_X, q) in registry.tau
-        # The plan matching the unrolled reference arm means the tuned
-        # arm IS the reference arm (same arithmetic program): report
-        # speedup 1.0 by construction, not a noisy self-measurement.
-        same_program = plan == autotune.TauPlan(variant="unrolled")
-
         t_unrolled = _time(_tau_unrolled, counts, q_hat)
         t_batched = _time(_tau_batched, counts, q_hat)
-        tuned_fn = _tau_tuned_fn(plan)
-        t_tuned = _time(tuned_fn, counts, q_hat)
 
         want = np.asarray(_tau_unrolled(counts, q_hat))
         identical &= bool(np.array_equal(want, np.asarray(_tau_batched(counts, q_hat))))
-        tuned_identical &= bool(np.array_equal(want, np.asarray(tuned_fn(counts, q_hat))))
 
         b_unrolled, b_batched = _tau_bytes(q)
-        b_tuned = autotune.tau_bytes(V_Z, V_X, q, plan)
-        speedup_tuned = 1.0 if same_program else round(t_unrolled / max(t_tuned, 1e-12), 3)
-        tuned_variants[f"q{q}"] = plan.variant + ("+lowprec" if plan.lowprec else "")
         tau_rows.append(
             dict(
                 q=q,
                 bytes_unrolled=b_unrolled,
                 bytes_batched=b_batched,
-                bytes_tuned=b_tuned,
                 bytes_reduction=round(b_unrolled / b_batched, 3),
                 us_unrolled=round(1e6 * t_unrolled, 1),
                 us_batched=round(1e6 * t_batched, 1),
-                us_tuned=round(1e6 * t_tuned, 1),
                 speedup=round(t_unrolled / max(t_batched, 1e-12), 3),
-                speedup_tuned=speedup_tuned,
-                tuned_variant=tuned_variants[f"q{q}"],
-                tuned_from_file=tuned_from_file,
-                same_program=same_program,
                 rounds_per_sec_unrolled=round(1.0 / max(t_unrolled, 1e-12), 1),
                 rounds_per_sec_batched=round(1.0 / max(t_batched, 1e-12), 1),
-                rounds_per_sec_tuned=round(1.0 / max(t_tuned, 1e-12), 1),
             )
         )
         rows.append(dict(name=f"stats_tau_q{q}_unrolled",
@@ -182,35 +133,21 @@ def run(rows: list) -> None:
         rows.append(dict(name=f"stats_tau_q{q}_batched",
                          us_per_call=1e6 * t_batched,
                          derived=round(b_batched / 2**20, 3)))
-        rows.append(dict(name=f"stats_tau_q{q}_tuned",
-                         us_per_call=1e6 * t_tuned,
-                         derived=round(b_tuned / 2**20, 3)))
 
-    # ingest: two-step vs fused vs what the committed plan dispatches
+    # ingest: two-step vs fused
     def two_step(z, x):
         c = ops.histogram(z, x, v_z=V_Z, v_x=V_X)
         return c, jnp.sum(c, axis=1)
 
-    ingest_plan = registry.ingest_plan(V_Z, V_X)
     t_two = _time(jax.jit(two_step), z, x)
     t_fused = _time(
-        jax.jit(lambda z, x: ops.histogram_with_rowsums(z, x, v_z=V_Z, v_x=V_X,
-                                                        plan="default")), z, x
+        jax.jit(lambda z, x: ops.histogram_with_rowsums(z, x, v_z=V_Z, v_x=V_X)), z, x
     )
-    t_ingest_tuned = _time(
-        jax.jit(lambda z, x: ops.histogram_with_rowsums(z, x, v_z=V_Z, v_x=V_X,
-                                                        plan=ingest_plan)), z, x
-    )
-    ingest_winner = "fused" if ingest_plan.fused else "two_step"
     ingest_saved = V_Z * V_X * 4  # the avoided delta-matrix re-read (fused form)
 
     by_q = {r["q"]: r for r in tau_rows}
     reduction_q8 = by_q[8]["bytes_reduction"]
     speedup_q8 = by_q[8]["speedup"]
-    tuned_speedup_min = min(r["speedup_tuned"] for r in tau_rows)
-    tuned_bytes_reduction_q8 = round(
-        by_q[8]["bytes_unrolled"] / by_q[8]["bytes_tuned"], 3
-    )
     # "independent of Q": the counts-stream term doesn't scale with Q —
     # going 1 -> 8 queries grows batched bytes only by the tiny targets
     # term, so the q8/q1 ratio stays near 1 (vs 8 for unrolled).
@@ -220,28 +157,17 @@ def run(rows: list) -> None:
     rows.append(dict(name="stats_tau_speedup_q8", us_per_call=0.0, derived=speedup_q8))
     rows.append(dict(name="stats_ingest_fused", us_per_call=1e6 * t_fused,
                      derived=round(ingest_saved / 2**20, 3)))
-    rows.append(dict(name="stats_ingest_tuned", us_per_call=1e6 * t_ingest_tuned,
-                     derived=1.0 if ingest_plan.fused else 0.0))
 
-    ok = identical and tuned_identical and reduction_q8 >= 4.0 and batched_growth < 2.0
+    ok = identical and reduction_q8 >= 4.0 and batched_growth < 2.0
     report = dict(
         config=dict(v_z=V_Z, v_x=V_X, n_samples=N_SAMPLES, reps=REPS,
                     smoke=SMOKE, **env_stamp()),
-        plan_file=str(plan_file) if plan_file else None,
         tau=tau_rows,
         ingest=dict(us_two_step=round(1e6 * t_two, 1),
                     us_fused=round(1e6 * t_fused, 1),
-                    us_tuned=round(1e6 * t_ingest_tuned, 1),
                     speedup=round(t_two / max(t_fused, 1e-12), 3),
-                    winner=ingest_winner,
-                    tuned_from_file=autotune.ingest_key(V_Z, V_X) in registry.ingest,
                     bytes_saved_per_round=ingest_saved),
         batched_bit_identical=identical,
-        tuned_bit_identical=tuned_identical,
-        tuned_variants=tuned_variants,
-        tuned_speedup_min=tuned_speedup_min,
-        tuned_tau_bytes_reduction_q8=tuned_bytes_reduction_q8,
-        ingest_winner=ingest_winner,
         batched_bytes_growth_q1_to_q8=round(batched_growth, 3),
         tau_bytes_reduction_q8=reduction_q8,
         ok=ok,
@@ -252,9 +178,7 @@ def run(rows: list) -> None:
     print(f"# stats_throughput: q8 tau bytes {by_q[8]['bytes_unrolled'] / 2**20:.1f}MB "
           f"-> {by_q[8]['bytes_batched'] / 2**20:.1f}MB ({reduction_q8:.1f}x, "
           f"growth q1->q8 {batched_growth:.2f}x), wall speedup {speedup_q8:.2f}x, "
-          f"tuned variants {tuned_variants} (speedup_min {tuned_speedup_min:.2f}), "
-          f"ingest winner {ingest_winner}, bit-identical={identical and tuned_identical}"
-          f" -> {'PASS' if ok else 'FAIL'}")
+          f"bit-identical={identical} -> {'PASS' if ok else 'FAIL'}")
     if SMOKE and not ok:
         raise SystemExit("stats_throughput smoke FAILED")
 

@@ -78,7 +78,7 @@ from repro.core.multiquery import (
 )
 from repro.core.policies import mark_window
 from repro.io import WindowData
-from repro.kernels import autotune, ops
+from repro.kernels import ops
 
 __all__ = [
     "cache_pspecs",
@@ -208,7 +208,6 @@ def make_distributed_round(
     model_axis: str = "model",
     histogram_impl: str = "auto",
     onehot_dtype=jnp.float32,
-    plans=None,
 ):
     """Build the jitted shard_map multi-query round for a given mesh.
 
@@ -219,27 +218,17 @@ def make_distributed_round(
     (padding = -1). All-reduce structure is as documented above; the
     statistics tail is `multiquery.apply_stats`, identical to the
     single-device scheduler's.
-
-    ``plans`` (an `autotune.PlanPair`) pins the tuned kernel variants;
-    None resolves from the plan registry at build time using the SHARD
-    shapes — each worker's kernels see (vz_shard, V_X), not the global
-    V_Z, so that is the key the tuner must have measured.
     """
     vz_shard = _check_vz(spec, mesh, model_axis)
     sample_axes = tuple(data_axes)
-    if plans is None:
-        plans = autotune.resolve_plans(
-            vz_shard, spec.v_x, spec.max_queries, metric=spec.metric
-        )
 
     def round_fn(state: MultiQueryState, z_idx: jax.Array, x_idx: jax.Array):
         state = _shard_ingest(
             state, z_idx, x_idx, spec=spec, vz_shard=vz_shard,
             sample_axes=sample_axes, model_axis=model_axis,
             histogram_impl=histogram_impl, onehot_dtype=onehot_dtype,
-            plan=plans.ingest,
         )
-        return _shard_stats(state, spec=spec, model_axis=model_axis, plan=plans.tau)
+        return _shard_stats(state, spec=spec, model_axis=model_axis)
 
     specs = multi_state_pspecs(model_axis=model_axis)
     sample_spec = P(sample_axes)
@@ -257,38 +246,31 @@ def _shard_ingest(
     model_axis: str,
     histogram_impl: str,
     onehot_dtype,
-    plan=None,
 ) -> MultiQueryState:
     """Ingest (inside shard_map): local histogram restricted to this
     model shard's candidate rows — an index shift, not a gather — with
-    the row-sum delta emitted from the same kernel pass (or the tuned
-    two-step form, per ``plan``), then ONE fused all-reduce of the
-    (counts, row-sum) delta pair over the data axes (a single psum
-    call, XLA fuses the pytree)."""
+    the row-sum delta emitted from the same kernel pass, then ONE fused
+    all-reduce of the (counts, row-sum) delta pair over the data axes
+    (a single psum call, XLA fuses the pytree)."""
     shard_id = jax.lax.axis_index(model_axis)
     z_local = z_idx - shard_id * vz_shard
     z_local = jnp.where((z_local >= 0) & (z_local < vz_shard), z_local, -1)
     h, rows = ops.histogram_with_rowsums(
         z_local, x_idx, v_z=vz_shard, v_x=spec.v_x,
         impl=histogram_impl, onehot_dtype=onehot_dtype,
-        plan=plan if plan is not None else "auto",
     )
     h, rows = jax.lax.psum((h, rows), sample_axes)
     return state._replace(counts=state.counts + h, n=state.n + rows)
 
 
 def _shard_stats(
-    state: MultiQueryState, *, spec: MultiQuerySpec, model_axis: str, plan=None
+    state: MultiQueryState, *, spec: MultiQuerySpec, model_axis: str
 ) -> MultiQueryState:
     """Statistics tail (inside shard_map): row-local Q-batched tau (ONE
-    kernel pass over this shard's counts rows scores every slot — or
-    the tuned variant ``plan`` selected; unoccupied slots masked to the
-    init value), tiny all-gather, then the shared vmapped per-query
-    assignment."""
-    tau_shard = ops.distance_multi(
-        state.counts, state.q_hat, metric=spec.metric,
-        plan=plan if plan is not None else "auto",
-    )  # (Q, vz_shard)
+    kernel pass over this shard's counts rows scores every slot;
+    unoccupied slots masked to the init value), tiny all-gather, then
+    the shared vmapped per-query assignment."""
+    tau_shard = ops.distance_multi(state.counts, state.q_hat, metric=spec.metric)  # (Q, vz_shard)
     tau_shard = jnp.where(state.occupied[:, None], tau_shard, 1.0)
     tau = jax.lax.all_gather(tau_shard, model_axis, axis=1, tiled=True)
     n_full = jax.lax.all_gather(state.n, model_axis, axis=0, tiled=True)
@@ -360,7 +342,6 @@ def make_pump_round(
     policy: str = "anyactive",
     histogram_impl: str = "auto",
     onehot_dtype=jnp.float32,
-    plans=None,
 ):
     """Build the jitted shard_map PUMP round: the fused sampling round
     (`multiquery.fused_round` semantics — mark + gather-mask + ingest +
@@ -381,16 +362,9 @@ def make_pump_round(
     fused_round's lax.cond (collectives inside a cond branch do not
     lower reliably under shard_map); selected leaves are bit-identical
     either way.
-
-    ``plans`` follows the `make_distributed_round` contract (shard-shape
-    plan key).
     """
     vz_shard = _check_vz(spec, mesh, model_axis)
     sample_axes = tuple(data_axes)
-    if plans is None:
-        plans = autotune.resolve_plans(
-            vz_shard, spec.v_x, spec.max_queries, metric=spec.metric
-        )
 
     def round_fn(state: MultiQueryState, cursor: SampleCursor, wd: WindowData):
         local_idx = wd.indices - _worker_lo(mesh, sample_axes, blocks_per_worker)
@@ -402,11 +376,8 @@ def make_pump_round(
             state, zw, xw, spec=spec, vz_shard=vz_shard,
             sample_axes=sample_axes, model_axis=model_axis,
             histogram_impl=histogram_impl, onehot_dtype=onehot_dtype,
-            plan=plans.ingest,
         )
-        new_state = _shard_stats(
-            new_state, spec=spec, model_axis=model_axis, plan=plans.tau
-        )
+        new_state = _shard_stats(new_state, spec=spec, model_axis=model_axis)
         n_marked = jax.lax.psum(jnp.sum(marks.astype(jnp.int32)), sample_axes)
         state = jax.tree.map(
             lambda new, old: jnp.where(n_marked > 0, new, old), new_state, state
@@ -428,20 +399,14 @@ def make_pump_ingest_round(
     model_axis: str = "model",
     histogram_impl: str = "auto",
     onehot_dtype=jnp.float32,
-    plans=None,
 ):
     """Build the jitted shard_map exact-completion round — the pump twin
     of `multiquery.ingest_round`: every unread block of each worker's
     window goes into the shared counts, no marking, no stats (the
     caller runs one stats step after the last chunk). Same signature
-    and placement contract as `make_pump_round` (including the
-    shard-shape ``plans`` key)."""
+    and placement contract as `make_pump_round`."""
     vz_shard = _check_vz(spec, mesh, model_axis)
     sample_axes = tuple(data_axes)
-    if plans is None:
-        plans = autotune.resolve_plans(
-            vz_shard, spec.v_x, spec.max_queries, metric=spec.metric
-        )
 
     def round_fn(state: MultiQueryState, cursor: SampleCursor, wd: WindowData):
         local_idx = wd.indices - _worker_lo(mesh, sample_axes, blocks_per_worker)
@@ -452,7 +417,6 @@ def make_pump_ingest_round(
             state, zw, xw, spec=spec, vz_shard=vz_shard,
             sample_axes=sample_axes, model_axis=model_axis,
             histogram_impl=histogram_impl, onehot_dtype=onehot_dtype,
-            plan=plans.ingest,
         )
         return state, _advance_shard_cursor(cursor, wd, marks, local_idx, sample_axes)
 
@@ -462,22 +426,13 @@ def make_pump_ingest_round(
     return _manual(mesh, round_fn, (specs, cspecs, wspecs), (specs, cspecs))
 
 
-def make_stats_step(
-    mesh, spec: MultiQuerySpec, *, model_axis: str = "model", plans=None
-):
+def make_stats_step(mesh, spec: MultiQuerySpec, *, model_axis: str = "model"):
     """Build the jitted shard_map statistics step over a candidate-sharded
-    state — the mesh twin of `multiquery.stats_step` (state -> state).
-    ``plans`` follows the `make_distributed_round` contract (shard-shape
-    plan key)."""
-    vz_shard = _check_vz(spec, mesh, model_axis)
-    if plans is None:
-        plans = autotune.resolve_plans(
-            vz_shard, spec.v_x, spec.max_queries, metric=spec.metric
-        )
+    state — the mesh twin of `multiquery.stats_step` (state -> state)."""
+    _check_vz(spec, mesh, model_axis)
     specs = multi_state_pspecs(model_axis=model_axis)
     return _manual(
-        mesh, partial(_shard_stats, spec=spec, model_axis=model_axis, plan=plans.tau),
-        (specs,), specs,
+        mesh, partial(_shard_stats, spec=spec, model_axis=model_axis), (specs,), specs
     )
 
 
@@ -487,7 +442,6 @@ def make_stream_rounds(
     *,
     model_axis: str = "model",
     policy: str = "anyactive",
-    plans=None,
 ) -> RoundFns:
     """Build the rounds `SharedCountsScheduler(mesh=...)` dispatches: ONE
     global window stream over a candidate-sharded counts matrix.
@@ -503,16 +457,10 @@ def make_stream_rounds(
     `ingest_round` / `stats_step` on the sharded state, as explicit
     shard_map programs (see the module docstring for why). V_Z must
     divide by the model axis, as for every candidate-sharded round.
-    ``plans`` follows the `make_distributed_round` contract (shard-shape
-    plan key).
     """
     vz_shard = _check_vz(spec, mesh, model_axis)
     data_axes = tuple(a for a in mesh.axis_names if a != model_axis)
     num_data = math.prod(mesh.shape[a] for a in data_axes)
-    if plans is None:
-        plans = autotune.resolve_plans(
-            vz_shard, spec.v_x, spec.max_queries, metric=spec.metric
-        )
 
     def ingest(state: MultiQueryState, wd: WindowData, marks: jax.Array):
         zw = jnp.where(marks[:, None], wd.z, jnp.int32(-1)).reshape(-1)
@@ -529,15 +477,12 @@ def make_stream_rounds(
         return _shard_ingest(
             state, zw, xw, spec=spec, vz_shard=vz_shard, sample_axes=data_axes,
             model_axis=model_axis, histogram_impl="auto", onehot_dtype=jnp.float32,
-            plan=plans.ingest,
         )
 
     def round_fn(state: MultiQueryState, cursor: SampleCursor, wd: WindowData):
         marks = mark_window(wd.bitmap, state.union_words, policy=policy)
         marks = marks & wd.valid & ~cursor.read_mask[wd.indices]
-        new_state = _shard_stats(
-            ingest(state, wd, marks), spec=spec, model_axis=model_axis, plan=plans.tau
-        )
+        new_state = _shard_stats(ingest(state, wd, marks), spec=spec, model_axis=model_axis)
         n_marked = jnp.sum(marks.astype(jnp.int32))
         state = jax.tree.map(
             lambda new, old: jnp.where(n_marked > 0, new, old), new_state, state
@@ -552,5 +497,5 @@ def make_stream_rounds(
     return RoundFns(
         round=_manual(mesh, round_fn, (specs, P(), P()), (specs, P())),
         ingest=_manual(mesh, ingest_fn, (specs, P(), P()), (specs, P())),
-        stats=make_stats_step(mesh, spec, model_axis=model_axis, plans=plans),
+        stats=make_stats_step(mesh, spec, model_axis=model_axis),
     )

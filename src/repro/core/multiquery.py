@@ -62,9 +62,8 @@ with admission/retirement.
 
 Pluggable metrics and query types: the spec's static ``metric`` ("l1" |
 "chi2" | "hellinger") selects WHICH registry distance the shared tau
-pass computes — threaded through `stats_step` exactly like the tuned
-kernel plan, so one scheduler serves one metric with per-metric
-autotune keys. Query TYPE is per-slot and dynamic: every slot carries a
+pass computes — threaded statically through `stats_step`, so one
+scheduler serves one metric. Query TYPE is per-slot and dynamic: every slot carries a
 ``qtype`` (0 = top-k, 1 = closeness) and a ``gap``, and `apply_stats`
 evaluates both retirement rules and selects per slot — admitting a
 closeness query next to live top-k queries therefore triggers NO
@@ -94,7 +93,7 @@ from repro.core.histsim import HistSimState
 from repro.core.policies import mark_window
 from repro.io import BlockSource, WindowData, as_block_source
 from repro.io.faults import WindowQuarantined, find_resilient
-from repro.kernels import autotune, ops
+from repro.kernels import ops
 from repro.kernels.histogram import ingest_lane_fill
 from repro.obs.spans import span, spanned
 from repro.obs.telemetry import Telemetry
@@ -199,9 +198,8 @@ class MultiQuerySpec:
     # None = no bound known (selection falls back to V_Z order stats).
     k_cap: Optional[int] = None
     # Registry distance the shared tau pass computes (and the bound
-    # family deviations go through) — static per scheduler, threaded
-    # like the kernel plan. "l1" reproduces the pre-metric-layer
-    # program bit for bit.
+    # family deviations go through) — static per scheduler. "l1"
+    # reproduces the pre-metric-layer program bit for bit.
     metric: str = "l1"
     # Failure-bound routing: "native" evaluates Theorem 1 at the
     # observation-aware ℓ1 budget (tighter for chi2/hellinger, never
@@ -454,22 +452,20 @@ def _or_reduce(words: jax.Array) -> jax.Array:
     return jax.lax.reduce(words, jnp.uint32(0), jax.lax.bitwise_or, dimensions=[0])
 
 
-@partial(jax.jit, static_argnames=("spec", "plan"))
+@partial(jax.jit, static_argnames=("spec",))
 def ingest(
     state: MultiQueryState,
     z_idx: jax.Array,
     x_idx: jax.Array,
     *,
     spec: MultiQuerySpec,
-    plan=None,
 ) -> MultiQueryState:
     """Accumulate a padded sample batch into the SHARED counts — one
     histogram-kernel launch serves every live query. The kernel emits
-    the per-candidate row-sum delta from the same pass (or via the
-    two-step form when the tuned ``plan`` measured it faster), so
-    advancing ``n_i`` costs no second sweep over the delta matrix."""
+    the per-candidate row-sum delta from the same pass, so advancing
+    ``n_i`` costs no second sweep over the delta matrix."""
     delta_counts, delta_n = ops.histogram_with_rowsums(
-        z_idx, x_idx, v_z=spec.v_z, v_x=spec.v_x, plan=plan if plan is not None else "auto"
+        z_idx, x_idx, v_z=spec.v_z, v_x=spec.v_x
     )
     return state._replace(
         counts=state.counts + delta_counts,
@@ -562,10 +558,8 @@ def apply_stats(
     )
 
 
-@partial(jax.jit, static_argnames=("spec", "plan"))
-def stats_step(
-    state: MultiQueryState, *, spec: MultiQuerySpec, plan=None
-) -> MultiQueryState:
+@partial(jax.jit, static_argnames=("spec",))
+def stats_step(state: MultiQueryState, *, spec: MultiQuerySpec) -> MultiQueryState:
     """One statistics-engine iteration for every slot — no Python loop.
 
     tau for ALL slots comes from ONE `ops.distance_multi` call (the
@@ -577,14 +571,9 @@ def stats_step(
     slots burned a full pass against a stale q_hat). Unoccupied slots
     are masked out of the tau update (pinned at the init value 1.0);
     the deviation assignment with each slot's (k, eps, delta) is
-    vmapped over the query axis via `apply_stats`. ``plan`` pins the
-    tuned tau variant (`autotune.TauPlan`); None consults the plan
-    registry at trace time.
+    vmapped over the query axis via `apply_stats`.
     """
-    tau = ops.distance_multi(
-        state.counts, state.q_hat, metric=spec.metric,
-        plan=plan if plan is not None else "auto",
-    )
+    tau = ops.distance_multi(state.counts, state.q_hat, metric=spec.metric)
     tau = jnp.where(state.occupied[:, None], tau, 1.0)
     return apply_stats(state, tau, state.n, spec=spec)
 
@@ -595,14 +584,9 @@ def run_round(
     x_idx: jax.Array,
     *,
     spec: MultiQuerySpec,
-    plans: Optional[autotune.PlanPair] = None,
 ) -> MultiQueryState:
     """Shared ingest + vmapped stats — one full multi-query round."""
-    return stats_step(
-        ingest(state, z_idx, x_idx, spec=spec, plan=plans.ingest if plans else None),
-        spec=spec,
-        plan=plans.tau if plans else None,
-    )
+    return stats_step(ingest(state, z_idx, x_idx, spec=spec), spec=spec)
 
 
 def _advance_cursor(cursor: SampleCursor, wd: WindowData, marks: jax.Array) -> SampleCursor:
@@ -623,7 +607,7 @@ def _advance_cursor(cursor: SampleCursor, wd: WindowData, marks: jax.Array) -> S
     )
 
 
-@partial(jax.jit, static_argnames=("spec", "policy", "plans"))
+@partial(jax.jit, static_argnames=("spec", "policy"))
 def fused_round(
     state: MultiQueryState,
     cursor: SampleCursor,
@@ -631,7 +615,6 @@ def fused_round(
     *,
     spec: MultiQuerySpec,
     policy: str,
-    plans: Optional[autotune.PlanPair] = None,
 ) -> tuple:
     """One device-resident sampling round: mark + gather-mask + ingest +
     vmapped stats + read bookkeeping, one dispatch, zero host syncs.
@@ -651,24 +634,19 @@ def fused_round(
     def with_round(st: MultiQueryState) -> MultiQueryState:
         zw = jnp.where(marks[:, None], wd.z, jnp.int32(-1)).reshape(-1)
         xw = jnp.where(marks[:, None], wd.x, jnp.int32(-1)).reshape(-1)
-        return stats_step(
-            ingest(st, zw, xw, spec=spec, plan=plans.ingest if plans else None),
-            spec=spec,
-            plan=plans.tau if plans else None,
-        )
+        return stats_step(ingest(st, zw, xw, spec=spec), spec=spec)
 
     state = jax.lax.cond(n_marked > 0, with_round, lambda st: st, state)
     return state, _advance_cursor(cursor, wd, marks)
 
 
-@partial(jax.jit, static_argnames=("spec", "plans"))
+@partial(jax.jit, static_argnames=("spec",))
 def ingest_round(
     state: MultiQueryState,
     cursor: SampleCursor,
     wd: WindowData,
     *,
     spec: MultiQuerySpec,
-    plans: Optional[autotune.PlanPair] = None,
 ) -> tuple:
     """Exact-completion round: ingest every unread block of the window
     into the shared counts, no marking, no stats (the caller runs one
@@ -677,7 +655,7 @@ def ingest_round(
     marks = wd.valid & ~cursor.read_mask[wd.indices]
     zw = jnp.where(marks[:, None], wd.z, jnp.int32(-1)).reshape(-1)
     xw = jnp.where(marks[:, None], wd.x, jnp.int32(-1)).reshape(-1)
-    state = ingest(state, zw, xw, spec=spec, plan=plans.ingest if plans else None)
+    state = ingest(state, zw, xw, spec=spec)
     return state, _advance_cursor(cursor, wd, marks)
 
 
@@ -915,7 +893,6 @@ class SharedCountsScheduler:
         mesh=None,
         model_axis: str = "model",
         telemetry: Optional[Telemetry] = None,
-        plans: Optional[autotune.PlanPair] = None,
     ):
         source: BlockSource = as_block_source(dataset)
         if spec.v_z != source.v_z or spec.v_x != source.v_x:
@@ -936,17 +913,6 @@ class SharedCountsScheduler:
         self.spec = spec
         self.policy = policy
         self.poll_every = poll_every
-        # Tuned kernel plans, resolved ONCE here (eagerly — with
-        # FASTMATCH_AUTOTUNE=1 this may measure and persist missing
-        # keys) and threaded statically through every jitted round, so
-        # one scheduler's whole lifetime runs one consistent plan.
-        self.plans = (
-            plans
-            if plans is not None
-            else autotune.resolve_plans(
-                spec.v_z, spec.v_x, spec.max_queries, metric=spec.metric
-            )
-        )
         nb = source.num_blocks
         self.window = max(1, min(window, nb))
 
@@ -957,7 +923,7 @@ class SharedCountsScheduler:
 
         self.state = init_multi_state(spec)
         self.cursor = self._place_cursor(init_cursor(nb))
-        self._rounds = self._make_rounds(mesh, model_axis, plans)
+        self._rounds = self._make_rounds(mesh, model_axis)
         if mesh is not None:
             from jax.sharding import NamedSharding
             from repro.core.distributed import multi_state_pspecs
@@ -1051,7 +1017,7 @@ class SharedCountsScheduler:
             reg.gauge(
                 "fastmatch_ingest_lane_fill",
                 "share of the ingest contraction's output cells that are real counts",
-            ).set(ingest_lane_fill(spec.v_z, spec.v_x, self.plans.ingest.z_tile))
+            ).set(ingest_lane_fill(spec.v_z, spec.v_x))
 
     # -- device placement hooks (overridden by the data-parallel pump) -----
 
@@ -1066,25 +1032,24 @@ class SharedCountsScheduler:
         same placement as a fresh cursor."""
         return cursor
 
-    def _make_rounds(self, mesh, model_axis: str, plans) -> RoundFns:
+    def _make_rounds(self, mesh, model_axis: str) -> RoundFns:
         """The device programs this scheduler dispatches, chosen once.
 
-        Without a mesh: `fused_round` / `ingest_round` / `stats_step`
-        under ``self.plans``. With one: the explicit shard_map rounds of
-        `repro.core.distributed.make_stream_rounds`, their plans keyed on
-        the shard shapes unless ``plans`` (the caller's pair) pins them.
+        Without a mesh: `fused_round` / `ingest_round` / `stats_step`.
+        With one: the explicit shard_map rounds of
+        `repro.core.distributed.make_stream_rounds`.
         `repro.core.pump.DistributedPump` overrides this with its
         per-worker rounds."""
         if mesh is None:
             return RoundFns(
-                round=partial(fused_round, spec=self.spec, policy=self.policy, plans=self.plans),
-                ingest=partial(ingest_round, spec=self.spec, plans=self.plans),
-                stats=partial(stats_step, spec=self.spec, plan=self.plans.tau),
+                round=partial(fused_round, spec=self.spec, policy=self.policy),
+                ingest=partial(ingest_round, spec=self.spec),
+                stats=partial(stats_step, spec=self.spec),
             )
         from repro.core.distributed import make_stream_rounds
 
         return make_stream_rounds(
-            mesh, self.spec, model_axis=model_axis, policy=self.policy, plans=plans
+            mesh, self.spec, model_axis=model_axis, policy=self.policy
         )
 
     def _global_read_mask(self) -> jax.Array:

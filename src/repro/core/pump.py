@@ -130,7 +130,6 @@ class DistributedPump(SharedCountsScheduler):
         histogram_impl: str = "auto",
         onehot_dtype=jnp.float32,
         telemetry=None,
-        plans=None,
     ):
         if not isinstance(dataset, BlockedDataset):
             raise TypeError(
@@ -185,18 +184,13 @@ class DistributedPump(SharedCountsScheduler):
             mesh=mesh,
             model_axis=model_axis,
             telemetry=telemetry,
-            plans=plans,
         )
 
-    def _make_rounds(self, mesh, model_axis: str, plans) -> RoundFns:
-        """The per-worker pump rounds and the mesh statistics step. The
-        shard rounds key their plans on the per-worker kernel shapes
-        (vz_shard rows), not the scheduler-level full V_Z — resolved
-        separately unless the caller pinned a pair explicitly."""
+    def _make_rounds(self, mesh, model_axis: str) -> RoundFns:
+        """The per-worker pump rounds and the mesh statistics step."""
         kw = dict(
             data_axes=self.data_axes, model_axis=model_axis,
             histogram_impl=self._histogram_impl, onehot_dtype=self._onehot_dtype,
-            plans=plans,
         )
         return RoundFns(
             round=make_pump_round(
@@ -206,7 +200,7 @@ class DistributedPump(SharedCountsScheduler):
             ingest=make_pump_ingest_round(
                 mesh, self.spec, blocks_per_worker=self._blocks_per_worker, **kw
             ),
-            stats=make_stats_step(mesh, self.spec, model_axis=model_axis, plans=plans),
+            stats=make_stats_step(mesh, self.spec, model_axis=model_axis),
         )
 
     # -- cursor placement / snapshot layout --------------------------------

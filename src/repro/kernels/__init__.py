@@ -1,6 +1,6 @@
 # OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
-from repro.kernels import autotune, ops
+from repro.kernels import ops
 
-__all__ = ["autotune", "ops"]
+__all__ = ["ops"]

@@ -73,8 +73,8 @@ def _packed_rows(v_z: int, v_x: int, z_tile: int) -> tuple:
 
 def ingest_lane_fill(v_z: int, v_x: int, z_tile: int = _Z_TILE) -> float:
     """Share of the contraction's computed output cells that are real
-    counts: V_Z * V_X over the padded (rows, 128) output, for a plan whose
-    row tile is ``z_tile``. Depends on the shape alone."""
+    counts: V_Z * V_X over the padded (rows, 128) output, for a row tile
+    of ``z_tile``. Depends on the shape alone."""
     _, rows_pad = _packed_rows(v_z, v_x, z_tile)
     return v_z * v_x / (rows_pad * _LANES)
 

@@ -6,8 +6,8 @@ engine's original hot loop, paper Sec 3). The metric layer
 the l1 instance of its Q=1 form emits the exact op sequence of the old
 kernel (load tile -> row sum -> max(row, 1) divide -> |diff| -> lane
 reduce), so this alias is bit-identical to the kernel it replaced.
-Kept for its import surface (`l1_distance_pallas`, `_MAX_VX`) — the
-autotuner's "unrolled" variant and older tests import it directly.
+Kept for its import surface (`l1_distance_pallas`, `_MAX_VX`): the
+legacy `ops.l1_distance` and older tests import it directly.
 
 Rows with zero mass return ||q_hat||_1 (= 1), matching ref.py.
 """

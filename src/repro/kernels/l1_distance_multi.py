@@ -7,7 +7,7 @@ two-sweep lane-tiled layouts) now lives score-generic in
 `repro.kernels.metrics.distance_multi_pallas` — the l1 instance emits
 the exact same ops as the kernel that used to live here, so this alias
 is bit-identical to it. Kept for its import surface
-(`l1_distance_multi_pallas`), used by the autotuner and kernel tests.
+(`l1_distance_multi_pallas`), used by kernel tests.
 """
 
 from __future__ import annotations

@@ -8,14 +8,13 @@ target distributions,
     tau[q, i] = sum_x score(r_hat[i, x], q_hat[q, x]).
 
 The score is the only thing that differs between distances, so the
-whole kernel zoo — the XLA reference forms, the fused-3D broadcast
-variant, and the Pallas single-/two-sweep Q-batched tile kernels — is
-written ONCE here, parameterized by a `MetricDef`, and ℓ1 becomes one
+XLA reference forms and the Pallas single-/two-sweep Q-batched tile
+kernels are written ONCE here, parameterized by a `MetricDef`, and ℓ1 becomes one
 registry instance (`ops.l1_distance_multi` is now a thin alias; its
 output is bit-identical to the pre-metric-layer kernels because the l1
 instance emits the exact same op sequence).
 
-Registry entries are `(score, l1_budget, native_l1_budget, bytes_model)`:
+Registry entries are `(score, l1_budget, native_l1_budget)`:
 
   score      — the elementwise lane term (runs inside the kernels);
   l1_budget  — the deviation half of the metric: an inverse modulus of
@@ -30,11 +29,6 @@ Registry entries are `(score, l1_budget, native_l1_budget, bytes_model)`:
                a max over independently valid budgets), so the implied
                sample complexity never exceeds the conservative one.
                Derivations in `core/bounds.py`.
-  bytes_model — analytic HBM traffic per tau round. All three metrics
-               stream the same bytes (they differ in VPU flops only),
-               so they share `streaming_tau_bytes`; the field exists so
-               a metric with different traffic (e.g. one needing a
-               second statistics pass) can say so to the autotuner.
 
 Metrics ship three instances:
 
@@ -69,10 +63,8 @@ __all__ = [
     "coerce_metric",
     "distance_ref",
     "distance_multi_ref",
-    "distance_multi_xla",
     "distance_pallas",
     "distance_multi_pallas",
-    "streaming_tau_bytes",
     "MAX_SINGLE_BLOCK_VX",
 ]
 
@@ -83,12 +75,12 @@ _Z_TILE = 256
 # Q=8 two-sweep kernel runs out of VMEM on a v5e). V_X beyond this is
 # lane-tiled.
 _X_TILE = 1024
-# Single-block V_X bound for the Q=1 (unrolled) kernel form.
+# Single-block V_X bound for the Q=1 kernel form.
 MAX_SINGLE_BLOCK_VX = 4096
 
 
 # ---------------------------------------------------------------------------
-# Elementwise scores (run inside the Pallas kernels AND the XLA forms)
+# Elementwise scores (run inside the Pallas kernels AND the XLA references)
 # ---------------------------------------------------------------------------
 
 
@@ -110,17 +102,9 @@ def _score_hellinger(r: jax.Array, q: jax.Array) -> jax.Array:
     return 0.5 * (d * d)
 
 
-def streaming_tau_bytes(
-    v_z: int, v_x: int, q: int, *, passes: int, counts_itemsize: int
-) -> int:
-    """HBM bytes per tau round for a streaming (counts-pass) metric:
-    ``passes`` reads of the counts matrix plus targets in / taus out."""
-    return passes * v_z * v_x * counts_itemsize + q * (v_x + v_z) * 4
-
-
 @dataclasses.dataclass(frozen=True)
 class MetricDef:
-    """One pluggable distance: score + deviation budget + traffic model."""
+    """One pluggable distance: score + deviation budget."""
 
     name: str
     score: Callable[[jax.Array, jax.Array], jax.Array]
@@ -130,7 +114,6 @@ class MetricDef:
     # is the IDENTITY — it must add zero ops so the refactored l1
     # bound path stays bit-identical to Theorem 1 as previously coded.
     l1_budget: Callable
-    bytes_model: Callable[..., int] = streaming_tau_bytes
     # tau of a candidate with zero sampled mass (r_hat = 0 vs a
     # normalized target): documentation + oracle value for tests.
     empty_row_tau: float = 1.0
@@ -257,21 +240,6 @@ def distance_multi_ref(counts: jax.Array, q_hat: jax.Array, *, metric="l1") -> j
     )
 
 
-def distance_multi_xla(counts: jax.Array, q_hat: jax.Array, *, metric="l1") -> jax.Array:
-    """(Q, V_Z) batched tau as one fused (Q, V_Z, V_X) broadcast — "let
-    XLA schedule it". Addition order over the lane axis matches the
-    stacked-2D form, so the result is bit-identical to
-    `distance_multi_ref`; only measured wall time differs (exactly what
-    `kernels.autotune` measures).
-    """
-    m = coerce_metric(metric)
-    counts = counts.astype(jnp.float32)
-    row = jnp.sum(counts, axis=1, keepdims=True)
-    r_hat = counts / jnp.maximum(row, 1.0)
-    q = q_hat.astype(jnp.float32)
-    return jnp.sum(m.score(r_hat[None, :, :], q[:, None, :]), axis=2)
-
-
 # ---------------------------------------------------------------------------
 # Pallas TPU kernels: the l1_distance_multi tile structure, score-generic
 # ---------------------------------------------------------------------------
@@ -338,12 +306,13 @@ def distance_multi_pallas(
     internally; q_hat padding is 0 and every registry score is 0 at
     (0, 0), so padded lanes contribute nothing.
 
-    ``sweeps`` selects the layout (an autotuner knob — both layouts are
-    bit-identical): 0 picks by padded V_X (single-sweep when V_X fits
-    one ``x_tile`` VMEM block, else the two-sweep lane-tiled form whose
-    phase 0 accumulates row sums into a VMEM scratch and phase 1
-    accumulates the per-query score partials), 1 forces single-sweep
-    (raises if V_X does not fit), 2 forces two-sweep.
+    ``sweeps`` selects the layout (both layouts are bit-identical; the
+    engine always passes 0, tests force the others): 0 picks by padded
+    V_X (single-sweep when V_X fits one ``x_tile`` VMEM block, else the
+    two-sweep lane-tiled form whose phase 0 accumulates row sums into a
+    VMEM scratch and phase 1 accumulates the per-query score partials),
+    1 forces single-sweep (raises if V_X does not fit), 2 forces
+    two-sweep.
     """
     score = coerce_metric(metric).score
     v_z, v_x = counts.shape
@@ -409,7 +378,7 @@ def distance_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     """(V_Z,) float32 single-query tau — the Q=1 instance of the batched
-    kernel (what the autotuner's "unrolled" variant stacks Q times).
+    kernel, reached only through the legacy `l1_distance` entry points.
     V_X must fit one VMEM block (<= `MAX_SINGLE_BLOCK_VX`).
     """
     if counts.shape[1] > MAX_SINGLE_BLOCK_VX:

@@ -1,7 +1,6 @@
 """Public jit'd entry points for the kernels package.
 
-Dispatch is three-axis — every hot-path call is the product of three
-independent, jit-static choices:
+Dispatch is two-axis, and both axes are jit-static:
 
 1. **Engine** (the ``impl`` argument): which code family runs.
    ``"auto"`` picks Pallas on TPU and the pure-jnp `metrics`/`ref`
@@ -10,89 +9,54 @@ independent, jit-static choices:
    ``interpret=True`` to validate the kernels themselves against the
    oracles.
 
-2. **Plan** (the ``plan`` argument on the two hot-path entry points):
-   WHICH measured-fastest variant of that engine runs, resolved from
-   `repro.kernels.autotune`'s committed per-backend plan file. Shapes
-   are concrete at trace time, so a ``plan="auto"`` registry lookup is
-   a plain dict get baked into the compiled program — zero dispatch
-   cost per call. A lookup miss, a stale plan file, or a plan the
-   engine/shape can't run falls back to `autotune.DEFAULT_TAU` /
-   `DEFAULT_INGEST`, which reproduce the pre-autotune dispatch bit for
-   bit. Pass an explicit `autotune.TauPlan` / `IngestPlan` to pin a
-   variant (the round-builders thread a resolved `PlanPair` through
-   statically), or ``plan="default"`` to ignore the registry.
-
-3. **Metric** (the ``metric`` argument on `distance_multi`): WHAT the
+2. **Metric** (the ``metric`` argument on `distance_multi`): WHAT the
    per-round computation scores against the shared counts matrix — an
    elementwise-lane distance from the `repro.kernels.metrics` registry
-   ("l1" | "chi2" | "hellinger"). The metric is a plain string, so it
-   is hashable and jit-static exactly like ``plan``; it is threaded the
-   same way (MultiQuerySpec -> fused_round / make_distributed_round /
-   make_pump_round -> this module). Metric and plan compose freely —
-   every tuned variant runs every metric — and autotune plan keys are
-   per-metric, because the score changes the VPU cost that decides
-   which variant wins. The ``metric="l1"`` default reproduces the
-   pre-metric-layer l1 ops bit for bit.
+   ("l1" | "chi2" | "hellinger"). It is a property of the spec, threaded
+   as a plain string (MultiQuerySpec -> fused_round /
+   make_distributed_round / make_pump_round -> this module).
 
-Plan-driven entry points (variants per engine; every variant of one
-metric is bit-identical on integer-valued counts — see
-tests/test_autotune.py and tests/test_metrics.py):
+Each job has one kernel per engine:
 
   ======================  ==============================================
-  op                      plan knobs
+  op                      Pallas engine / ref engine
   ======================  ==============================================
-  distance_multi          variant: "batched" (one counts pass scores all
-                          Q targets — `metrics.distance_multi_pallas` /
-                          `metrics.distance_multi_ref`), "unrolled" (Q
-                          single-query passes stacked), "xla" (fused 3D
-                          broadcast, `metrics.distance_multi_xla`);
-                          z_tile / x_tile / sweeps (Pallas tiling and
-                          single- vs two-sweep V_X phase); lowprec
-                          (uint16 counts traffic behind a runtime
-                          overflow gate, exact by construction and
-                          metric-agnostic — kernels upcast per tile).
-  histogram_with_rowsums  fused: one pass
-                          (`histogram_with_rowsums_pallas` /
-                          `histogram_with_rowsums_ref`) vs the two-step
-                          histogram + separate row reduction (on the
-                          Pallas engine the same kernel, rows reduced
-                          from its reshaped counts either way);
-                          s_tile / z_tile (Pallas tiling: samples, and
-                          packed rows of the flat z * V_X + x key).
+  histogram_with_rowsums  `histogram_with_rowsums_pallas` (one pass over
+                          the packed z * V_X + x key, row sums reduced
+                          from the reshaped counts) /
+                          `ref.histogram_with_rowsums_ref`.
                           ``impl="matmul"`` (chunked one-hot
-                          contraction) bypasses the plan — it is an
-                          explicit engine request, not a tuned variant.
+                          contraction) is an explicit engine request.
                           No metric axis: the counts matrix is shared
                           by every metric and query type.
+  distance_multi          `metrics.distance_multi_pallas` (one counts
+                          pass scores all Q targets; single- or
+                          two-sweep layout by padded V_X) /
+                          `metrics.distance_multi_ref`.
+  histogram               histogram_pallas / histogram_ref / "matmul".
+  anyactive               anyactive_pallas / anyactive_ref.
   ======================  ==============================================
 
-Fixed-dispatch entry points (no plan — one variant per engine):
-`histogram` (histogram_pallas / histogram_ref / "matmul"),
-`l1_distance` (Q=1 l1 — `l1_distance_pallas`, V_X <= 4096 /
-`ref.l1_distance_ref`), `anyactive` (anyactive_pallas / anyactive_ref).
-`l1_distance_multi` is the l1 pin of `distance_multi`, kept for its
-import surface.
-
-`l1_distance` is the Q=1 legacy entry point; every round in the engine
-(histsim / multiquery / distributed / pump) routes through
-`distance_multi` and `histogram_with_rowsums`, so the plan file is
-what the serving loop actually runs. After editing the plan file on
-disk, call `autotune.reload()` — it clears the jit caches that hold the
-previously-baked plans.
+The kernels' tile sizes are their own constants. `l1_distance` (Q=1
+l1, V_X <= 4096) and `l1_distance_multi` (the l1 pin of
+`distance_multi`) are legacy entry points kept for their import
+surface; every round in the engine (histsim / multiquery / distributed
+/ pump) routes through `distance_multi` and `histogram_with_rowsums`.
 """
 
 from __future__ import annotations
 
+import collections
 import functools
 import re
-from typing import Literal, Union
+from typing import Literal
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import autotune, ref
+from repro.kernels import metrics, ref
 from repro.kernels.anyactive import anyactive_pallas
-from repro.kernels.histogram import histogram_pallas
+from repro.kernels.histogram import histogram_pallas, histogram_with_rowsums_pallas
 from repro.kernels.l1_distance import l1_distance_pallas
 
 __all__ = [
@@ -103,14 +67,11 @@ __all__ = [
     "l1_distance_multi",
     "anyactive",
     "default_impl",
+    "tpu_kernel_calls",
     "tpu_kernels_in",
 ]
 
 Impl = Literal["auto", "pallas", "ref"]
-# "auto": trace-time registry lookup; "default": pin the pre-autotune
-# dispatch; or an explicit plan instance (hashable -> jit-static).
-TauPlanArg = Union[str, None, autotune.TauPlan]
-IngestPlanArg = Union[str, None, autotune.IngestPlan]
 
 
 def default_impl() -> str:
@@ -121,14 +82,19 @@ def _resolve(impl: Impl) -> str:
     return default_impl() if impl == "auto" else impl
 
 
-def tpu_kernels_in(hlo_text: str) -> set:
-    """Names of the Pallas TPU kernels (``tpu_custom_call``) in the text
+def tpu_kernel_calls(hlo_text: str) -> collections.Counter:
+    """Calls per Pallas TPU kernel name (``tpu_custom_call``) in the text
     of a compiled program (``jit(f).lower(...).compile().as_text()``)."""
-    return {
+    return collections.Counter(
         re.search(r"%([A-Za-z_]\w*?)(?:\.\d+)? = ", line).group(1)
         for line in hlo_text.splitlines()
         if 'custom_call_target="tpu_custom_call"' in line
-    }
+    )
+
+
+def tpu_kernels_in(hlo_text: str) -> set:
+    """Names of the Pallas TPU kernels in the text of a compiled program."""
+    return set(tpu_kernel_calls(hlo_text))
 
 
 @functools.partial(jax.jit, static_argnames=("v_z", "v_x", "impl", "interpret", "onehot_dtype"))
@@ -156,10 +122,7 @@ def histogram(
     return ref.histogram_ref(z_idx, x_idx, v_z=v_z, v_x=v_x)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("v_z", "v_x", "impl", "interpret", "onehot_dtype", "plan"),
-)
+@functools.partial(jax.jit, static_argnames=("v_z", "v_x", "impl", "interpret", "onehot_dtype"))
 def histogram_with_rowsums(
     z_idx: jax.Array,
     x_idx: jax.Array,
@@ -169,30 +132,23 @@ def histogram_with_rowsums(
     impl: Impl = "auto",
     interpret: bool = False,
     onehot_dtype=jnp.float32,
-    plan: IngestPlanArg = "auto",
 ) -> tuple:
     """((V_Z, V_X), (V_Z,)) histogram + row-sum delta.
 
     rows == counts.sum(axis=1) exactly (integer-valued f32 counts), so
     `ingest` can advance ``n_i`` without re-reading the delta matrix.
-    Same impl choices as `histogram`; ``plan`` picks the tuned variant
-    (fused one-pass vs two-step, Pallas tiles — see the module
-    docstring). ``impl="matmul"`` bypasses the plan.
+    Same impl choices as `histogram`.
     """
     if impl == "matmul":
         counts = ref.histogram_matmul(
             z_idx, x_idx, v_z=v_z, v_x=v_x, onehot_dtype=onehot_dtype
         )
         return counts, jnp.sum(counts, axis=1)
-    return autotune.run_ingest(
-        z_idx,
-        x_idx,
-        v_z=v_z,
-        v_x=v_x,
-        plan=autotune.coerce_ingest_plan(plan, v_z, v_x),
-        engine=_resolve(impl),
-        interpret=interpret,
-    )
+    if _resolve(impl) == "pallas":
+        return histogram_with_rowsums_pallas(
+            z_idx, x_idx, v_z=v_z, v_x=v_x, interpret=interpret
+        )
+    return ref.histogram_with_rowsums_ref(z_idx, x_idx, v_z=v_z, v_x=v_x)
 
 
 @functools.partial(jax.jit, static_argnames=("impl", "interpret"))
@@ -209,7 +165,7 @@ def l1_distance(
     return ref.l1_distance_ref(counts, q_hat)
 
 
-@functools.partial(jax.jit, static_argnames=("metric", "impl", "interpret", "plan"))
+@functools.partial(jax.jit, static_argnames=("metric", "impl", "interpret"))
 def distance_multi(
     counts: jax.Array,
     q_hat: jax.Array,
@@ -217,27 +173,18 @@ def distance_multi(
     metric: str = "l1",
     impl: Impl = "auto",
     interpret: bool = False,
-    plan: TauPlanArg = "auto",
 ) -> jax.Array:
     """(Q, V_Z) f32 batched distances for a (Q, V_X) target matrix.
 
     ``metric`` picks WHAT is computed (registry score: "l1" | "chi2" |
-    "hellinger" — squared Hellinger); ``plan`` picks the tuned variant
-    of HOW (batched one-pass / Q-unrolled / fused-3D "xla", plus Pallas
-    tiles, sweep phase, and the uint16 low-precision counts path — see
-    the module docstring). The default plan is the batched form: HBM
-    traffic Q * V_Z * V_X -> V_Z * V_X + Q * V_X, independent of Q and
-    of the metric. Within one metric all variants are bit-identical on
-    integer-valued counts, so the plan is a pure wall-clock choice.
-    Unlike the Q=1 `l1_distance`, V_X is unbounded (lane-tiled on TPU).
+    "hellinger" — squared Hellinger). One counts pass scores every
+    target: HBM traffic is V_Z * V_X + Q * V_X, independent of Q and of
+    the metric. Unlike the Q=1 `l1_distance`, V_X is unbounded
+    (lane-tiled on TPU).
     """
-    tau_plan = autotune.coerce_tau_plan(
-        plan, counts.shape[0], counts.shape[1], q_hat.shape[0], metric
-    )
-    return autotune.run_tau(
-        counts, q_hat, plan=tau_plan, engine=_resolve(impl),
-        interpret=interpret, metric=metric,
-    )
+    if _resolve(impl) == "pallas":
+        return metrics.distance_multi_pallas(counts, q_hat, metric=metric, interpret=interpret)
+    return metrics.distance_multi_ref(counts, q_hat, metric=metric)
 
 
 def l1_distance_multi(
@@ -246,13 +193,10 @@ def l1_distance_multi(
     *,
     impl: Impl = "auto",
     interpret: bool = False,
-    plan: TauPlanArg = "auto",
 ) -> jax.Array:
     """`distance_multi` pinned to metric="l1" (the pre-metric-layer
     entry point, bit-identical to it; kept for its import surface)."""
-    return distance_multi(
-        counts, q_hat, metric="l1", impl=impl, interpret=interpret, plan=plan
-    )
+    return distance_multi(counts, q_hat, metric="l1", impl=impl, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("impl", "interpret"))

@@ -23,7 +23,6 @@ __all__ = [
     "histogram_with_rowsums_ref",
     "l1_distance_ref",
     "l1_distance_multi_ref",
-    "l1_distance_multi_xla",
     "anyactive_ref",
 ]
 
@@ -152,29 +151,6 @@ def l1_distance_multi_ref(counts: jax.Array, q_hat: jax.Array) -> jax.Array:
       (Q, V_Z) float32 distances.
     """
     return metrics.distance_multi_ref(counts, q_hat, metric="l1")
-
-
-def l1_distance_multi_xla(counts: jax.Array, q_hat: jax.Array) -> jax.Array:
-    """Q-batched tau as one fused (Q, V_Z, V_X) broadcast — "let XLA
-    schedule it".
-
-    The autotuner's third variant: same normalization as
-    `l1_distance_multi_ref` (r_hat hoisted once), but the Q per-query
-    reductions are expressed as a single 3D |diff| -> lane reduce and
-    XLA's fusion machinery decides the loop order. Addition order over
-    the lane axis matches the stacked-2D form, so on integer-valued
-    counts the result is bit-identical to `l1_distance_multi_ref` and to
-    the Pallas kernel; only the measured wall time differs — whether the
-    fused 3D form wins is exactly what `kernels.autotune` measures.
-
-    Args:
-      counts: (V_Z, V_X) nonnegative counts.
-      q_hat: (Q, V_X) normalized targets.
-
-    Returns:
-      (Q, V_Z) float32 distances.
-    """
-    return metrics.distance_multi_xla(counts, q_hat, metric="l1")
 
 
 def anyactive_ref(bitmap: jax.Array, active_words: jax.Array) -> jax.Array:

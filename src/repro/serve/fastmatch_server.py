@@ -234,7 +234,6 @@ class MatchServer:
         autosave_rounds: Optional[int] = None,
         checkpoint_keep_last: int = 3,
         telemetry=None,
-        kernel_plans=None,
         metric: str = "l1",
         bounds_mode: str = "native",
         prune: bool = False,
@@ -268,16 +267,9 @@ class MatchServer:
         # CheckpointManager; None (default) leaves every layer on its
         # untouched zero-overhead path.
         #
-        # kernel_plans: an `autotune.PlanPair` pinning the tuned kernel
-        # variants for every round this server dispatches; None (the
-        # default) resolves from the committed per-backend plan file at
-        # scheduler construction. `server.kernel_plans` exposes what
-        # was resolved.
-        #
         # metric: the registry distance every query on this server is
-        # stated in ("l1" | "chi2" | "hellinger") — static per server,
-        # like the kernel plan; see docs/guarantees.md for what to
-        # expect from non-l1 bounds.
+        # stated in ("l1" | "chi2" | "hellinger") — static per server;
+        # see docs/guarantees.md for what to expect from non-l1 bounds.
         #
         # bounds_mode: "native" (default) routes failure bounds through
         # the observation-aware per-metric budgets (never looser than
@@ -325,7 +317,6 @@ class MatchServer:
                 poll_every=poll_every,
                 prefetch=prefetch,
                 telemetry=telemetry,
-                plans=kernel_plans,
             )
         else:
             if tuple(data_axes) != ("data",):
@@ -362,7 +353,6 @@ class MatchServer:
                 mesh=mesh,
                 model_axis=model_axis,
                 telemetry=telemetry,
-                plans=kernel_plans,
             )
         self.max_passes = max_passes
         self._mesh = mesh
@@ -397,13 +387,6 @@ class MatchServer:
         self._pass_pos = 0
         self._pass_read = 0
         self._pass_start_rounds = 0
-
-    @property
-    def kernel_plans(self):
-        """The `autotune.PlanPair` this server's scheduler-level rounds
-        run (the pump's shard rounds key on the per-worker shard shapes
-        — see `core.pump.DistributedPump`)."""
-        return self.scheduler.plans
 
     # -- request queue -----------------------------------------------------
 

@@ -396,7 +396,7 @@ class TestServerTelemetry:
         _, _, blocked = dataset
         srv = MatchServer(blocked, max_queries=2, lookahead=64, telemetry=True)
         gauge = srv.telemetry.registry.get("fastmatch_ingest_lane_fill")
-        assert gauge.value == ingest_lane_fill(32, 16, srv.scheduler.plans.ingest.z_tile)
+        assert gauge.value == ingest_lane_fill(32, 16)
         assert gauge.value == 32 * 16 / (8 * 128)
 
     def test_trace_and_prometheus_exports(self, dataset, targets, tmp_path):

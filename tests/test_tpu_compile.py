@@ -18,7 +18,9 @@ for a described (not attached) v5e 2x2, at the served shapes:
     size, which must read the tables in place (no table-sized copy);
   * the whole jitted serving round (`multiquery.fused_round`) at the
     taxi_q1 and flights_q1 shapes, and at flights_q2's read mask over 600M
-    tuples, holding all three kernels;
+    tuples, holding each of the three kernels once; the statistics step
+    (one distance kernel, every metric) and the exact-completion round
+    (one ingest kernel) at the deployments' shapes;
   * the four-chip rounds: the pump on a 4x1 mesh and the single-stream
     candidate-sharded mesh on 1x4.
 
@@ -40,7 +42,7 @@ from repro.core import multiquery as mq
 from repro.core.bitmap import words_for
 from repro.io import WindowData
 from repro.io.block_source import LANES, gather_window
-from repro.kernels import autotune, ops
+from repro.kernels import ops
 
 SHAPES = [(161, 24), (7548, 24), (191, 2), (161, 161)]
 BLOCK = 512
@@ -91,6 +93,11 @@ def _kernels(compiled) -> set:
     return ops.tpu_kernels_in(compiled.as_text())
 
 
+def _kernel_calls(compiled):
+    """Calls per kernel name: a set of names cannot tell one call from two."""
+    return ops.tpu_kernel_calls(compiled.as_text())
+
+
 @pytest.mark.parametrize(
     "v_z,v_x,n",
     [(vz, vx, SERVED_WINDOW) for vz, vx in SHAPES] + [(161, 24, 16 * BLOCK)],
@@ -98,7 +105,7 @@ def _kernels(compiled) -> set:
 def test_histogram_with_rowsums_compiles(one_chip, v_z, v_x, n):
     ids = _sds((n,), jnp.int32, one_chip)
     compiled = ops.histogram_with_rowsums.lower(
-        ids, ids, v_z=v_z, v_x=v_x, impl="pallas", plan="default"
+        ids, ids, v_z=v_z, v_x=v_x, impl="pallas"
     ).compile()
     assert _kernels(compiled) == {"histogram_with_rowsums"}
     # One call per ingest, which the ingest roofline's byte count assumes;
@@ -112,7 +119,7 @@ def test_distance_multi_compiles(one_chip, v_z, v_x, q):
     compiled = ops.distance_multi.lower(
         _sds((v_z, v_x), jnp.float32, one_chip),
         _sds((q, v_x), jnp.float32, one_chip),
-        impl="pallas", plan="default",
+        impl="pallas",
     ).compile()
     assert _kernels(compiled) == {"distance_multi"}
 
@@ -175,15 +182,37 @@ def _round_args(spec, num_blocks, lookahead, state_sharding, rep):
                          [(7548, 24, 62_500), (161, 24, 11_719), (161, 24, 1_171_875)])
 def test_fused_round_compiles(one_chip, pallas_engine, v_z, v_x, num_blocks):
     """The whole served round (taxi_q1 / flights_q1 shapes, max_queries=8,
-    lookahead=512) compiles with all three kernels in it; the last case is a
-    flights_q2 cold session's round, its read mask over 600M tuples."""
+    lookahead=512) compiles with each of the three kernels called once; the
+    last case is a flights_q2 cold session's round, its read mask over 600M
+    tuples."""
     spec = mq.MultiQuerySpec(v_z=v_z, v_x=v_x, max_queries=8, k_cap=10)
     state_sharding = jax.tree.map(lambda _: one_chip, mq.init_multi_state(spec))
     args = _round_args(spec, num_blocks, 512, state_sharding, one_chip)
-    compiled = mq.fused_round.lower(
-        *args, spec=spec, policy="anyactive", plans=autotune.PlanPair()
-    ).compile()
-    assert _kernels(compiled) == KERNELS
+    compiled = mq.fused_round.lower(*args, spec=spec, policy="anyactive").compile()
+    assert _kernel_calls(compiled) == dict.fromkeys(KERNELS, 1)
+
+
+@pytest.mark.parametrize("metric", ["l1", "chi2", "hellinger"])
+@pytest.mark.parametrize("v_z,v_x", [(7548, 24), (161, 24)])
+def test_stats_step_compiles(one_chip, pallas_engine, v_z, v_x, metric):
+    """The statistics step at the taxi and flights shapes: one distance
+    kernel scores all eight slots, for every metric."""
+    spec = mq.MultiQuerySpec(v_z=v_z, v_x=v_x, max_queries=8, k_cap=10, metric=metric)
+    state_sharding = jax.tree.map(lambda _: one_chip, mq.init_multi_state(spec))
+    state, _, _ = _round_args(spec, 1, 1, state_sharding, one_chip)
+    compiled = mq.stats_step.lower(state, spec=spec).compile()
+    assert _kernel_calls(compiled) == {"distance_multi": 1}
+
+
+@pytest.mark.parametrize("v_z,v_x,num_blocks", [(7548, 24, 62_500), (161, 24, 11_719)])
+def test_ingest_round_compiles(one_chip, pallas_engine, v_z, v_x, num_blocks):
+    """The exact-completion round (Scan) at the taxi and flights shapes:
+    one ingest kernel over the 512-block window, no marking, no stats."""
+    spec = mq.MultiQuerySpec(v_z=v_z, v_x=v_x, max_queries=8, k_cap=10)
+    state_sharding = jax.tree.map(lambda _: one_chip, mq.init_multi_state(spec))
+    args = _round_args(spec, num_blocks, 512, state_sharding, one_chip)
+    compiled = mq.ingest_round.lower(*args, spec=spec).compile()
+    assert _kernel_calls(compiled) == {"histogram_with_rowsums": 1}
 
 
 @pytest.mark.parametrize("layout", ["pump_4x1", "stream_1x4"])
@@ -201,7 +230,7 @@ def test_four_chip_rounds_compile(topo, pallas_engine, layout):
     rep = NamedSharding(mesh, P())
     if layout == "stream_1x4":
         args = _round_args(spec, num_blocks, lookahead, state_sharding, rep)
-        fn = dist.make_stream_rounds(mesh, spec, plans=autotune.PlanPair()).round
+        fn = dist.make_stream_rounds(mesh, spec).round
     else:
         per = -(-num_blocks // 4)
         state, _, _ = _round_args(spec, num_blocks, lookahead, state_sharding, rep)
@@ -215,9 +244,7 @@ def test_four_chip_rounds_compile(topo, pallas_engine, layout):
             wd, dist.window_pspecs(),
         )
         args = (state, cursor, wd)
-        fn = dist.make_pump_round(
-            mesh, spec, blocks_per_worker=per, plans=autotune.PlanPair()
-        )
+        fn = dist.make_pump_round(mesh, spec, blocks_per_worker=per)
     compiled = fn.lower(*args).compile()
     assert _kernels(compiled) == KERNELS
     assert "all-reduce" in compiled.as_text()
